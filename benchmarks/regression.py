@@ -89,8 +89,9 @@ def main(argv=None) -> int:
         ap.error(f"unknown bench(es) {unknown}; have {sorted(registry)}")
 
     rows = []
-    for name in only:
-        rows.extend(registry[name]())
+    for name in registry:       # registry order: see benchmarks.run
+        if name in only:
+            rows.extend(registry[name]())
     measured = parse_rows(rows)
     regressions = compare(measured, baseline)
 

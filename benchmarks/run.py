@@ -36,36 +36,43 @@ def pipeline_bench():
     return rows
 
 
+#: name -> "module:function".  Modules are imported only when their
+#: bench runs, and ``campaign`` comes first: its process-scheduler
+#: section starts worker processes that need the accelerator, so it must
+#: run from a parent that has not imported jax yet (one process per
+#: chip).
+_BENCHES = {
+    "campaign": "benchmarks.campaign_bench:campaign_bench",
+    "pipeline": "benchmarks.run:pipeline_bench",
+    "cachesim": "benchmarks.cachesim_bench:cachesim_bench",
+    "composer": "benchmarks.composer_bench:composer_bench",
+    "devices": "benchmarks.devices_bench:devices_bench",
+    "sweep": "benchmarks.sweep_bench:sweep_bench",
+    "table4": "benchmarks.paper_tables:table4_pka",
+    "fig5": "benchmarks.fig5_retention:fig5_retention",
+    "table6": "benchmarks.paper_tables:table6_energy",
+    "table7": "benchmarks.paper_tables:table7_hetero",
+    "table8": "benchmarks.paper_tables:table8_orphans",
+    "table9": "benchmarks.paper_tables:table9_pe_size",
+    "fig8": "benchmarks.paper_tables:fig8_lifetimes",
+    "fig10": "benchmarks.paper_tables:fig10_dataflow",
+    "kernels": "benchmarks.kernels_bench:kernels_bench",
+}
+
+
+def _lazy(spec: str):
+    def run():
+        import importlib
+        module, fn = spec.split(":")
+        return getattr(importlib.import_module(module), fn)()
+    return run
+
+
 def bench_registry() -> dict:
     """name -> bench function, each returning CSV rows
-    (``name,us_per_call,derived``).  Shared with
-    ``benchmarks.regression`` (the CI regression gate)."""
-    from benchmarks import paper_tables as pt
-    from benchmarks.cachesim_bench import cachesim_bench
-    from benchmarks.campaign_bench import campaign_bench
-    from benchmarks.composer_bench import composer_bench
-    from benchmarks.devices_bench import devices_bench
-    from benchmarks.fig5_retention import fig5_retention
-    from benchmarks.kernels_bench import kernels_bench
-    from benchmarks.sweep_bench import sweep_bench
-
-    return {
-        "pipeline": pipeline_bench,
-        "cachesim": cachesim_bench,
-        "campaign": campaign_bench,
-        "composer": composer_bench,
-        "devices": devices_bench,
-        "sweep": sweep_bench,
-        "table4": pt.table4_pka,
-        "fig5": fig5_retention,
-        "table6": pt.table6_energy,
-        "table7": pt.table7_hetero,
-        "table8": pt.table8_orphans,
-        "table9": pt.table9_pe_size,
-        "fig8": pt.fig8_lifetimes,
-        "fig10": pt.fig10_dataflow,
-        "kernels": kernels_bench,
-    }
+    (``name,us_per_call,derived``), in the order they must run.  Shared
+    with ``benchmarks.regression`` (the CI regression gate)."""
+    return {name: _lazy(spec) for name, spec in _BENCHES.items()}
 
 
 def main() -> None:

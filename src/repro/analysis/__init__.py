@@ -27,6 +27,8 @@ Rules (see docs/API.md, "Architecture contracts"):
   atomic-write          cluster/ and checkpoint/ never write files with
                         a raw ``open(path, "w")`` outside the
                         tmp-file+rename / O_EXCL helpers
+  jax-compat            jax-version shims (``enable_x64``) come from
+                        ``repro.compat`` only
 
 Import contract: this package is stdlib-only (it must run in CI and in
 campaign planning environments without jax/numpy) — and declares itself
@@ -42,18 +44,19 @@ from repro.analysis.dtypes import DtypeSafetyRule
 from repro.analysis.registry import RegistryConformanceRule
 from repro.analysis.schema import SchemaDriftRule, update_schema_manifest
 from repro.analysis.atomic import AtomicWriteRule
+from repro.analysis.compat import JaxCompatRule
 
 
 def default_rules():
     """The repo's rule set, in stable reporting order."""
     return (ImportPurityRule(), DtypeSafetyRule(),
             RegistryConformanceRule(), SchemaDriftRule(),
-            AtomicWriteRule())
+            AtomicWriteRule(), JaxCompatRule())
 
 
 def run_check(root: str | None = None, rules=None,
               baseline: dict | None = None) -> list:
-    """Run ``rules`` (default: all five) over the tree at ``root`` and
+    """Run ``rules`` (default: all six) over the tree at ``root`` and
     return the surviving findings — suppressions and the baseline
     already applied, sorted for stable output."""
     ctx = AnalysisContext(default_root() if root is None else root)
@@ -68,8 +71,9 @@ def run_check(root: str | None = None, rules=None,
 
 __all__ = [
     "AnalysisContext", "AtomicWriteRule", "DtypeSafetyRule", "Finding",
-    "ImportContract", "ImportPurityRule", "RegistryConformanceRule",
-    "SchemaDriftRule", "default_root", "default_rules", "filter_baseline",
+    "ImportContract", "ImportPurityRule", "JaxCompatRule",
+    "RegistryConformanceRule", "SchemaDriftRule", "default_root",
+    "default_rules", "filter_baseline",
     "filter_suppressed", "load_baseline", "run_check",
     "update_schema_manifest", "write_baseline",
 ]

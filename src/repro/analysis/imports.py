@@ -69,6 +69,8 @@ DEFAULT_CONTRACTS = (
                    exempt=("repro.compose.jax_engine",
                            "repro.compose.executor")),
     ImportContract("repro.__main__", ("jax", "numpy")),
+    # a campaign parent resolves its workers' compile cache without jax
+    ImportContract("repro.runtime.compile_cache", ("jax", "numpy")),
 )
 
 

@@ -28,7 +28,7 @@ Select via ``HierarchyConfig(simulator="scalar")`` (or the ``simulator=``
 kwarg through ``ProfileSession("gpu")`` / ``CacheHierarchyBackend.run``).
 
 Cycle stamps, line addresses, and the LRU clock are carried as **int64**
-(under a scoped ``jax.experimental.enable_x64``): line addresses >= 2**31
+(under a scoped ``repro.compat.enable_x64``): line addresses >= 2**31
 and multi-billion-cycle streams are exact, matching the int64 trace
 contract of ``repro.core.trace``.
 
@@ -47,8 +47,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
+from repro.compat import enable_x64
 from repro.core.api import ProfileResult, register_backend
 from repro.core.trace import Trace, chunk_trace
 

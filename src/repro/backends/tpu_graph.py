@@ -2,7 +2,8 @@
 
 Generates memory traces for the framework's *own* models: the jaxpr of a
 jitted step function is walked op by op; each op advances a cycle cursor by
-its roofline time on one TPU v5e core (197 TFLOP/s bf16, 819 GB/s HBM), and
+its roofline time on one TPU v5e chip (peaks from
+``repro.launch.roofline.CHIP_PEAKS``), and
 each intermediate buffer contributes
 
   - a *write* burst when its producer op completes (HBM -> VMEM fill /
@@ -28,9 +29,9 @@ import numpy as np
 
 from repro.core.api import ProfileResult, register_backend
 from repro.core.trace import Trace, chunk_trace
+from repro.launch.roofline import V5E, chip_peaks
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+_PEAKS = chip_peaks(V5E)    # the chip this backend models, not the host
 BLOCK_BYTES = 4096
 _HASH = np.uint64(11400714819323198485)
 
@@ -136,8 +137,8 @@ def trace_jaxpr(
                        if hasattr(v, "aval"))
             out_b = sum(_aval_bytes(v.aval) for v in eqn.outvars)
             total_b = (in_b + out_b) * mult
-            dur = max(1, int(max(flops / PEAK_FLOPS,
-                                 total_b / HBM_BW) * clock_hz))
+            dur = max(1, int(max(flops / _PEAKS["flops_bf16"],
+                                 total_b / _PEAKS["hbm_bw"]) * clock_hz))
             t0 = cursor[0]
             for v in eqn.invars:
                 if hasattr(v, "aval") and hasattr(v, "count"):

@@ -40,6 +40,7 @@ import traceback
 from repro.cluster.ledger import (DEFAULT_LEASE_TTL_S, JobLedger,
                                   default_worker_id)
 from repro.cluster.store import ArtifactStore
+from repro.runtime import compile_cache
 from repro.runtime.fault_tolerance import RetryPolicy
 
 _FAULT_ENV = "GAINSIGHT_WORKER_FAULT"
@@ -139,8 +140,10 @@ def run_worker(store_dir: str, *, worker_id: str | None = None,
                 tally["cache_hits"] += 1
                 continue
             if runner is None:            # lazy: leases before jax load
-                runner = runner_from_manifest(store.read_manifest(),
-                                              store_dir)
+                manifest = store.read_manifest()
+                # before this process's first jit (see compile_cache)
+                compile_cache.configure(manifest.get("compile_cache"))
+                runner = runner_from_manifest(manifest, store_dir)
             job = runner.job_for_key(rec.key)
             with _Heartbeat(ledger, rec.key, worker) as hb:
                 artifact = runner._execute(job)

@@ -31,7 +31,7 @@ from repro.compose.types import Composition
 
 _ENGINE_EXPORTS = ("evaluate", "compose", "composition_csv_rows",
                    "address_groups", "sorted_trace_view",
-                   "configure_compile_cache", "compile_stats")
+                   "compile_stats")
 
 __all__ = [
     "AddressGroups", "AssignmentPolicy", "BankQuantizedPolicy",

@@ -42,11 +42,8 @@ lazily — this module stays jax-free), which keeps the trace state
 device-resident across calls (see :func:`sorted_trace_view`) and
 agrees with the NumPy oracle bit-identically on capacity and to ~1e-9
 relative energy (``tests/test_jax_engine.py``,
-``tests/test_executor.py``).  :func:`configure_compile_cache` points
-jax's persistent compilation cache at a shared directory (campaign
-workers warm-start from it) and :func:`compile_stats` reports compile
-telemetry — both are safe to call without jax installed until a cache
-path is actually configured.
+``tests/test_executor.py``).  :func:`compile_stats` reports compile
+telemetry; it is safe to call before jax is imported.
 """
 
 from __future__ import annotations
@@ -229,24 +226,16 @@ def sorted_trace_view(stats: SubpartitionStats, raw,
     return view
 
 
-def configure_compile_cache(path: str) -> str:
-    """Point jax's persistent compilation cache at ``path`` so later
-    ``engine="jax"`` compiles are written there and warm-started from
-    it (campaigns pass ``<cache_dir>/jax-cache`` inside the shared
-    artifact store).  Imports jax — only call when the jax engine is
-    actually in play."""
-    from repro.compose import executor  # lazy: keeps this module jax-free
-    return executor.configure_compilation_cache(path)
-
-
 def compile_stats() -> dict:
     """Jax compile telemetry (jit entries, persistent-cache hits and
-    misses) for campaign job rows.  Jax-free until the executor has
-    actually been imported: reports zeros otherwise."""
+    misses, the device the kernels run on) for campaign job rows.
+    Jax-free until the executor has actually been imported: reports
+    zeros and no device otherwise."""
     import sys
     if "repro.compose.executor" not in sys.modules:
-        return {"jit_entries": 0, "persistent_cache_hits": 0,
-                "persistent_cache_misses": 0, "cache_dir": None}
+        from repro.runtime.compile_cache import counters
+        return {"jit_entries": 0, **counters(),
+                "platform": None, "device_kind": None}
     from repro.compose import executor
     return executor.compile_stats()
 

@@ -41,12 +41,10 @@ shape.  This module removes all three costs for ``engine="jax"``:
   coefficients zeroed before any ``0 * inf`` could produce NaN, and
   padded candidates are sliced off on the host.
 
-* **Persistent compilation cache** — :func:`configure_compilation_cache`
-  points jax's persistent compile cache at a directory (campaigns use
-  ``<cache_dir>/jax-cache`` inside the shared ``ArtifactStore``), so
-  process workers warm-start from each other's compiles.
-  :func:`compile_stats` exposes jit-entry counts and persistent-cache
-  hit/miss telemetry for the campaign report.
+* **Compile telemetry** — :func:`compile_stats` exposes jit-entry
+  counts, the persistent-cache hit/miss counters of
+  :mod:`repro.runtime.compile_cache` and the device the kernels ran on,
+  for the campaign report.
 
 Thread safety: dispatch is serialized on
 :data:`repro.compose.jax_engine._DISPATCH_LOCK` (shared with the
@@ -60,20 +58,19 @@ across engines.
 Import contract: like ``jax_engine``, this module imports jax at module
 level and is exempt from the ``repro.compose`` import-purity contract
 (``repro check``); it must only be imported lazily, from
-:func:`repro.compose.engine.evaluate` / ``configure_compile_cache``.
+:func:`repro.compose.engine.evaluate` / ``compile_stats``.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 import weakref
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
+from repro.compat import enable_x64
 from repro.compose.jax_engine import (_DISPATCH_LOCK, _base_policy,
                                       _host_weighted_fracs, supports)
 from repro.compose.policies import RefreshFreePolicy
@@ -112,56 +109,16 @@ def _slab_size(d_pad: int, l_pad: int, n_cands: int, itemsize: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache + telemetry
+# compile telemetry
 # ---------------------------------------------------------------------------
-
-_cache_dir: str | None = None
-_persistent = {"hits": 0, "misses": 0}
-_listener_registered = False
-
-
-def _on_cache_event(event: str, **_kw) -> None:
-    if event == "/jax/compilation_cache/cache_hits":
-        _persistent["hits"] += 1
-    elif event == "/jax/compilation_cache/cache_misses":
-        _persistent["misses"] += 1
-
-
-def configure_compilation_cache(path: str) -> str:
-    """Point jax's persistent compilation cache at ``path`` (created if
-    missing) and start counting hits/misses.  Process-global and
-    idempotent: reconfiguring with the same path is a no-op, so every
-    runner in the stack can call it defensively.  Campaigns store the
-    cache inside the shared ``ArtifactStore`` (``<cache_dir>/jax-cache``)
-    so worker processes warm-start from each other's compiles."""
-    global _cache_dir, _listener_registered
-    path = os.path.abspath(path)
-    if _cache_dir == path:
-        return path
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # CPU compiles are fast and small; cache everything, or workers
-    # would never see a warm entry.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax latches "is the cache in use?" at the first compile of the
-    # process; anything jitted before this call (the profiling
-    # frontend, usually) would leave the cache permanently disabled.
-    from jax.experimental.compilation_cache import compilation_cache
-    compilation_cache.reset_cache()
-    _cache_dir = path
-    if not _listener_registered:
-        from jax import monitoring
-        monitoring.register_event_listener(_on_cache_event)
-        _listener_registered = True
-    return path
-
 
 def compile_stats() -> dict:
     """Compile telemetry for campaign job rows: total jit cache entries
     across the fused and per-chunk kernels (deltas across a job count
-    its new compiles) plus persistent-cache hit/miss counters."""
+    its new compiles), persistent-cache hit/miss counters, and the
+    platform and ``device_kind`` the kernels run on."""
     from repro.compose import jax_engine
+    from repro.runtime.compile_cache import counters
     kernels = (_rf_fused, _ra_grouped, _ra_ungrouped,
                jax_engine._refresh_free_kernel,
                jax_engine._refresh_aware_kernel,
@@ -173,10 +130,9 @@ def compile_stats() -> dict:
             entries += fn._cache_size()
         except Exception:       # noqa: BLE001 - telemetry must not raise
             pass
-    return {"jit_entries": entries,
-            "persistent_cache_hits": _persistent["hits"],
-            "persistent_cache_misses": _persistent["misses"],
-            "cache_dir": _cache_dir}
+    dev = jax.devices()[0]
+    return {"jit_entries": entries, **counters(),
+            "platform": dev.platform, "device_kind": dev.device_kind}
 
 
 # ---------------------------------------------------------------------------
@@ -316,8 +272,16 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
     resident addr-sorted arrays.  Same decomposition as the PR-9 kernel
     (separable base terms + one refresh segment sum) so argmin ties
     resolve identically; the candidate-independent ``segment_sum`` base
-    terms are hoisted out of the vmap.  Padded addresses are masked out
-    of the pick counts; padded lifetimes contribute exact zeros."""
+    terms are hoisted out of the candidate loop.  Padded addresses are
+    masked out of the pick counts; padded lifetimes contribute exact
+    zeros.
+
+    Every per-candidate array keeps the lifetime or address axis last
+    ([D, L], [D, A]) and the refresh segment sums run one device row at
+    a time over 1-D data: on a TPU an array whose last axis is the
+    device (or candidate) axis is padded to 128 lanes, which at a few
+    million lifetimes does not fit in HBM.  Candidates run one after
+    another (``lax.map``) for the same reason."""
     rb = reads * bits
     ss = functools.partial(jax.ops.segment_sum, segment_ids=seg,
                            num_segments=n_seg, indices_are_sorted=True)
@@ -326,7 +290,8 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
     amask = jnp.arange(n_seg) < n_addr
     dev_ids = jnp.arange(ret.shape[1])
 
-    def one(ret_r, rf_r, wf_r, pad_r):
+    def one(args):
+        ret_r, rf_r, wf_r, pad_r = args
         refresh_e = (jnp.maximum(
             jnp.ceil(lt[None, :] / ret_r[:, None]) - 1.0, 0.0)
             * bits[None, :])                                # [D, L]
@@ -336,16 +301,16 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
              + rw[:, None] * refresh_e)
         e = jnp.where(pad_r[:, None], jnp.inf, e)
         energy = e.min(axis=0).sum() * 1e-15
-        per_addr = (wf_r[None, :] * ssb[:, None]
-                    + rf_r[None, :] * ssrb[:, None]
-                    + rw[None, :] * ss(refresh_e.T))        # [A, D]
-        per_addr = jnp.where(pad_r[None, :], jnp.inf, per_addr)
-        ad = jnp.argmin(per_addr, axis=1)
-        counts = ((ad[:, None] == dev_ids[None, :])
-                  & amask[:, None]).sum(axis=0)
+        per_addr = jnp.stack([
+            wf_r[d] * ssb + rf_r[d] * ssrb + rw[d] * ss(refresh_e[d])
+            for d in range(ret_r.shape[0])])                # [D, A]
+        per_addr = jnp.where(pad_r[:, None], jnp.inf, per_addr)
+        ad = jnp.argmin(per_addr, axis=0)
+        counts = ((ad[None, :] == dev_ids[:, None])
+                  & amask[None, :]).sum(axis=1)
         return energy, counts.astype(jnp.float64)
 
-    return jax.vmap(one)(ret, read_fj, write_fj, pad)
+    return jax.lax.map(one, (ret, read_fj, write_fj, pad))
 
 
 @jax.jit

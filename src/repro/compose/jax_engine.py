@@ -15,7 +15,7 @@ profile/sweep/campaign CLIs); the NumPy path stays the default and
 keeps the bit-for-bit seed guarantee.
 
 Numerical contract: everything runs in float64 under a scoped
-``jax.experimental.enable_x64`` (as ``repro.core.lifetime`` does for
+``repro.compat.enable_x64`` (as ``repro.core.lifetime`` does for
 int64), computing the *same* reductions as the NumPy kernels — only
 the float summation order differs, so the two engines agree within
 ~1e-9 relative energy (``tests/test_jax_engine.py`` locks this
@@ -59,8 +59,8 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
+from repro.compat import enable_x64
 from repro.compose.policies import (BankQuantizedPolicy, PolicyBatch,
                                     RefreshAwarePolicy, RefreshFreePolicy)
 

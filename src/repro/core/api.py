@@ -156,14 +156,10 @@ class ProfileSession:
 
     def __init__(self, backend: Backend | str | None = None, *,
                  devices: Sequence[DeviceModel | str] | None = None,
-                 compile_cache: str | None = None,
                  **backend_cfg):
         self.backend = (get_backend(backend) if isinstance(backend, str)
                         else backend)
         self.devices = resolve_devices(devices)
-        # persistent jax compilation cache dir, used by compose()/sweep()
-        # when engine="jax" (no effect on the default numpy engine)
-        self.compile_cache = compile_cache
         self._backend_cfg = dict(backend_cfg)
         self._result: ProfileResult | None = None
         self._report: dict | None = None
@@ -285,9 +281,6 @@ class ProfileSession:
             self.analyze()
         devs = resolve_devices(devices) if devices is not None \
             else self.devices
-        if engine == "jax" and self.compile_cache:
-            from repro.compose.engine import configure_compile_cache
-            configure_compile_cache(self.compile_cache)
         for name, (st, raw) in self._stats.items():
             comp = compose_stats(st, raw=raw, devices=devs,
                                  clock_hz=self._clock_hz, policy=policy,
@@ -320,8 +313,7 @@ class ProfileSession:
         from repro.sweep import SweepRunner
         self._require_analyzed()
         runner = SweepRunner(grid, workers=workers, policy=policy,
-                             engine=engine,
-                             compile_cache=self.compile_cache)
+                             engine=engine)
         result = runner.run_session(self)
         if attach:
             self._report["sweep"] = {

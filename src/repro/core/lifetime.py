@@ -32,7 +32,7 @@ Cycle stamps and addresses are carried as **int64 end-to-end** (the trace
 schema stores them as int64): cycle counts past 2**31 (~2.1 s at 1 GHz,
 i.e. any multi-step streamed workload) and line addresses >= 2**31 are
 exact, not silently wrapped.  The extraction runs its jitted segment ops
-under a scoped ``jax.experimental.enable_x64`` so the 64-bit arithmetic
+under a scoped ``repro.compat.enable_x64`` so the 64-bit arithmetic
 survives jax's default 32-bit mode without flipping the global flag.
 """
 
@@ -44,8 +44,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
+from repro.compat import enable_x64
 from repro.core.trace import Trace
 
 # "no read yet" sentinel: below any real int64 cycle stamp, with headroom

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import pcast, shard_map
-
 
 def pipeline_forward(mesh, stage_axis: str, stage_fn, params_stacked,
                      x_microbatches):
@@ -73,8 +71,8 @@ def pipeline_forward(mesh, stage_axis: str, stage_fn, params_stacked,
         held0 = jnp.zeros(mb_shape, xs.dtype)
         outs0 = jnp.zeros((M,) + mb_shape, xs.dtype)
         # mark the carries as stage-varying for shard_map's VMA tracking
-        held0 = pcast(held0, (stage_axis,), to="varying")
-        outs0 = pcast(outs0, (stage_axis,), to="varying")
+        held0 = jax.lax.pcast(held0, (stage_axis,), to="varying")
+        outs0 = jax.lax.pcast(outs0, (stage_axis,), to="varying")
         (_, outs), _ = jax.lax.scan(tick, (held0, outs0),
                                     jnp.arange(n_ticks))
         # replicate the last stage's outputs to every stage (masked psum:
@@ -85,5 +83,5 @@ def pipeline_forward(mesh, stage_axis: str, stage_fn, params_stacked,
 
     in_specs = (jax.tree.map(lambda _: P(stage_axis), params_stacked),
                 P())
-    return shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=P())(params_stacked, x_microbatches)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=P())(params_stacked, x_microbatches)

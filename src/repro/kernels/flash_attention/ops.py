@@ -3,8 +3,8 @@
 ``flash_attention`` accepts the model layout [B, S, H, hd] (heads after
 sequence) and is fully differentiable: the custom VJP dispatches the
 Pallas backward kernels (FA-2 two-pass), so neither direction ever
-materializes S^2 probabilities in HBM.  On non-TPU hosts the kernels run
-in interpret mode automatically.
+materializes S^2 probabilities in HBM.  The kernels run compiled on a
+TPU and interpreted on the CPU backend (:func:`repro.kernels.interpret_mode`).
 """
 
 from __future__ import annotations
@@ -13,30 +13,24 @@ from functools import partial
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 from repro.kernels.flash_attention.kernel_bwd import \
     flash_attention_bwd_bhsd
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_bhsd(q, k, v, causal, q_block, kv_block):
     o, _ = flash_attention_bhsd(q, k, v, causal=causal, q_block=q_block,
                                 kv_block=kv_block,
-                                interpret=not _on_tpu())
+                                interpret=interpret_mode())
     return o
 
 
 def _flash_fwd(q, k, v, causal, q_block, kv_block):
     o, lse = flash_attention_bhsd(q, k, v, causal=causal,
                                   q_block=q_block, kv_block=kv_block,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
     return o, (q, k, v, o, lse)
 
 
@@ -47,7 +41,7 @@ def _flash_bwd(causal, q_block, kv_block, res, do):
     G = H // KV
     dq, dk_h, dv_h = flash_attention_bwd_bhsd(
         q, k, v, o, lse, do, causal=causal, q_block=q_block,
-        kv_block=kv_block, interpret=not _on_tpu())
+        kv_block=kv_block, interpret=interpret_mode())
     # GQA: sum per-query-head contributions into kv heads
     Skv = k.shape[2]
     dk = dk_h.reshape(B, KV, G, Skv, hd).sum(2).astype(k.dtype)

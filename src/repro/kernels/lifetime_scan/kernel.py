@@ -65,10 +65,10 @@ def _lifetime_kernel(th_ref, tl_ref, a_ref, w_ref, eh_ref, el_ref,
         carry_scr[5] = jnp.int32(0)
         carry_scr[6] = jnp.int32(0)
 
-    th = th_ref[...]
-    tl = tl_ref[...]
-    a = a_ref[...]
-    w = w_ref[...].astype(bool)
+    th = th_ref[0, :]
+    tl = tl_ref[0, :]
+    a = a_ref[0, :]
+    w = w_ref[0, :].astype(bool)
     eh = eh_ref[...]
     el = el_ref[...]
 
@@ -82,13 +82,22 @@ def _lifetime_kernel(th_ref, tl_ref, a_ref, w_ref, eh_ref, el_ref,
 
     prev_a = jnp.concatenate([prev_addr[None], a[:-1]])
     boundary = (a != prev_a) | w
-    sid = jnp.cumsum(boundary.astype(jnp.int32))      # carry-segment = 0
-    nb = sid[-1]
+    # inclusive prefix count of boundaries (carry-segment = 0) as one
+    # triangular matmul: Mosaic has no cumsum, and f32 counts <= block
+    # are exact
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (block, block), 1))
+    sid = jnp.dot(boundary.astype(jnp.float32)[None, :],
+                  tri.astype(jnp.float32),
+                  preferred_element_type=jnp.float32
+                  ).reshape(block).astype(jnp.int32)
+    nb = sid.max()                  # sid is nondecreasing: its last entry
 
     ids = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)  # seg cols
     O = sid[:, None] == ids                            # [event, seg]
     r = ~w
-    Or = O & r[:, None]
+    # Mosaic turns int32 vectors into columns, not bool ones
+    Or = O & (r.astype(jnp.int32)[:, None] > 0)
 
     # per-segment first event: lexicographic (hi, lo) min, two masked
     # passes (min hi, then min lo among events at that hi)
@@ -134,26 +143,32 @@ def _lifetime_kernel(th_ref, tl_ref, a_ref, w_ref, eh_ref, el_ref,
     d_lo = jnp.where(ok, d_lo, 0)
 
     # bin by limb-pair comparison against integer edges (exact)
-    ge_lo = (d_hi[:, None] > eh[None, :-1]) | \
-        ((d_hi[:, None] == eh[None, :-1]) & (d_lo[:, None] >= el[None, :-1]))
-    lt_hi = (d_hi[:, None] < eh[None, 1:]) | \
-        ((d_hi[:, None] == eh[None, 1:]) & (d_lo[:, None] < el[None, 1:]))
-    in_bin = ge_lo & lt_hi & live[:, None]
+    eh0, el0 = eh[:-1][None, :], el[:-1][None, :]     # lower edges
+    eh1, el1 = eh[1:][None, :], el[1:][None, :]       # upper edges
+    ge_lo = (d_hi[:, None] > eh0) | \
+        ((d_hi[:, None] == eh0) & (d_lo[:, None] >= el0))
+    lt_hi = (d_hi[:, None] < eh1) | \
+        ((d_hi[:, None] == eh1) & (d_lo[:, None] < el1))
+    in_bin = ge_lo & lt_hi & (live.astype(jnp.int32)[:, None] > 0)
     hist_scr[...] += in_bin.astype(jnp.float32).sum(axis=0)
 
     ltf = d_hi.astype(jnp.float32) * jnp.float32(LO_MOD) + \
         d_lo.astype(jnp.float32)
-    stats_scr[0] += jnp.sum(live.astype(jnp.float32))
-    stats_scr[1] += jnp.sum(orphan.astype(jnp.float32))
-    stats_scr[2] += jnp.sum(ltf * live.astype(jnp.float32))
-    stats_scr[3] = jnp.maximum(stats_scr[3], ltf.max())
-    stats_scr[4] += jnp.sum(r.astype(jnp.float32))
-    stats_scr[5] += jnp.sum(w.astype(jnp.float32))
+    # VMEM takes no scalar stores: place the block's sums in their lanes
+    # and update the stats vector in one store (lane 3 is a running max)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (8,), 0)
+    add = jnp.zeros((8,), jnp.float32)
+    for k, v in ((0, live), (1, orphan), (4, r), (5, w)):
+        add = jnp.where(lane == k, jnp.sum(v.astype(jnp.float32)), add)
+    add = jnp.where(lane == 2, jnp.sum(ltf * live.astype(jnp.float32)), add)
+    cur = stats_scr[...]
+    stats_scr[...] = jnp.where(lane == 3, jnp.maximum(cur, ltf.max()),
+                               cur + add)
 
     # new carry = segment nb (the still-open one); sel picks exactly one
     # element, so a masked sum extracts it (works for -1 sentinels too)
     sel = seg_ids == nb
-    carry_scr[0] = a[-1]
+    carry_scr[0] = jnp.sum(jnp.where(seg_ids == block - 1, a, 0))
     carry_scr[1] = jnp.sum(jnp.where(sel, sh, 0))
     carry_scr[2] = jnp.sum(jnp.where(sel, sl, 0))
     carry_scr[3] = jnp.sum(jnp.where(sel, lh, 0))
@@ -183,11 +198,13 @@ def lifetime_scan_sorted(t_hi, t_lo, addr, is_write, edges_hi, edges_lo,
         functools.partial(_lifetime_kernel, block=block, n_blocks=n_blocks,
                           n_bins=n_bins),
         grid=(n_blocks,),
+        # events arrive as [n_blocks, 1, block], one row per step: a 1-D
+        # block would not match XLA's tiling of a long 1-D operand
         in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
+            pl.BlockSpec((None, 1, block), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, block), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, block), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, block), lambda i: (i, 0, 0)),
             pl.BlockSpec((n_bins + 1,), lambda i: (0,)),
             pl.BlockSpec((n_bins + 1,), lambda i: (0,)),
         ],
@@ -205,7 +222,7 @@ def lifetime_scan_sorted(t_hi, t_lo, addr, is_write, edges_hi, edges_lo,
             pltpu.SMEM((7,), jnp.int32),
         ],
         interpret=interpret,
-    )(t_hi.astype(jnp.int32), t_lo.astype(jnp.int32),
-      addr.astype(jnp.int32), is_write.astype(jnp.int32),
+    )(*(x.astype(jnp.int32).reshape(n_blocks, 1, block)
+        for x in (t_hi, t_lo, addr, is_write)),
       edges_hi.astype(jnp.int32), edges_lo.astype(jnp.int32))
     return hist, stats

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.lifetime_scan.kernel import (LO_BITS, LO_MOD,
                                                 lifetime_scan_sorted)
 
@@ -69,13 +70,6 @@ class KernelRangeError(OverflowError):
             f"[{lo}, {hi}] exceeds the valid half-open range "
             f"[{limit[0]}, {limit[1]}) (offending extreme: "
             f"{hi if hi >= limit[1] else lo}); {remediation}")
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def default_edges(n_bins: int = 64, lo_cycles: float = 1.0,
@@ -120,7 +114,7 @@ def _run(t_hi, t_lo, addr, w, edges_hi, edges_lo, block):
     ws = jnp.concatenate([ws, jnp.ones((n_pad,), ws.dtype)])
     hist, stats = lifetime_scan_sorted(
         th, tl, as_, ws, edges_hi, edges_lo, block=block,
-        n_bins=edges_hi.shape[0] - 1, interpret=not _on_tpu())
+        n_bins=edges_hi.shape[0] - 1, interpret=interpret_mode())
     # remove pad bookkeeping: n_pad-1 closed orphan pad segments, n_pad
     # pad writes
     stats = stats.at[1].add(-(n_pad - 1)).at[5].add(-n_pad)

@@ -7,14 +7,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_scan_chunked
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("chunk",))
@@ -31,5 +25,5 @@ def ssd_scan(x, dt, A, B, C, D=None, chunk: int = 64):
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
     y = ssd_scan_chunked(x, dt, A, B, C, D, chunk=chunk,
-                         interpret=not _on_tpu())
+                         interpret=interpret_mode())
     return y[:, :l]

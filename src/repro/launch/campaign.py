@@ -61,6 +61,7 @@ import traceback
 from typing import Mapping, Sequence
 
 from repro.launch import parse_floats as _floats
+from repro.runtime.compile_cache import cache_dir as compile_cache_dir
 from repro.workloads import (canonical_backend, get_workload,
                              resolve_workloads)
 
@@ -195,11 +196,13 @@ class CampaignRunner:
         energy).  Deliberately *not* a cache-key component: both
         engines produce the same artifacts within tolerance, so cached
         results are reusable across engines.
-    compile_cache : persistent jax compilation-cache directory shared
-        by every job (and worker process) of the campaign.  Defaults to
-        ``<cache_dir>/jax-cache`` when ``engine="jax"`` and a cache/
-        store directory exists, so process workers warm-start from each
-        other's compiles; ignored under ``engine="numpy"``.  Like
+    compile_cache : persistent jax compilation-cache directory that
+        process workers configure before their first jit, resolved by
+        :func:`repro.runtime.compile_cache.cache_dir`
+        (``JAX_COMPILATION_CACHE_DIR`` wins, else this argument, else
+        ``<checkout>/.jax-cache``), so workers warm-start from each
+        other's compiles.  The thread scheduler runs in the caller's
+        process, whose cache the caller (the CLI) configures.  Like
         ``engine`` it stays out of the cache key — compiled code never
         changes results.
     scheduler : ``"thread"`` (in-process pool, the PR-4 path kept
@@ -245,10 +248,8 @@ class CampaignRunner:
                 else backends)))
         self.jobs = max(1, int(jobs))
         self.cache_dir = cache_dir
-        self.compile_cache = compile_cache
-        if (self.compile_cache is None and self.engine == "jax"
-                and self.cache_dir):
-            self.compile_cache = os.path.join(self.cache_dir, "jax-cache")
+        self.compile_cache = compile_cache_dir(compile_cache)
+        self._chip_holder = None    # the worker allowed the accelerator
         self.seq = seq
         self.params = {k: dict(v) for k, v in (params or {}).items()}
         self.backend_cfg = {canonical_backend(k): dict(v)
@@ -353,14 +354,11 @@ class CampaignRunner:
         before = None
         if self.engine == "jax":
             from repro.compose import engine as compose_engine
-            if self.compile_cache:
-                compose_engine.configure_compile_cache(self.compile_cache)
             before = compose_engine.compile_stats()
         spec = self._spec_for(job.workload)
         workload, cfg = spec.build(job.backend)
         cfg = {**cfg, **dict(job.cfg)}
-        session = ProfileSession(job.backend, devices=self.devices,
-                                 compile_cache=self.compile_cache)
+        session = ProfileSession(job.backend, devices=self.devices)
         session.profile(workload, **cfg).analyze()
         session.compose(policy=self.policy, engine=self.engine)
         report = session.report()
@@ -414,7 +412,9 @@ class CampaignRunner:
                     after["persistent_cache_misses"]
                     - before["persistent_cache_misses"]),
                 "warm": after["jit_entries"] == before["jit_entries"],
-                "cache_dir": after["cache_dir"]}
+                "cache_dir": after["cache_dir"],
+                "platform": after["platform"],
+                "device_kind": after["device_kind"]}
         return artifact
 
     def job_for_key(self, key: str) -> CampaignJob:
@@ -522,9 +522,6 @@ class CampaignRunner:
         from repro.runtime.fault_tolerance import RetryPolicy
         if not self.cache_dir:
             self.cache_dir = tempfile.mkdtemp(prefix="gainsight-campaign-")
-            if self.compile_cache is None and self.engine == "jax":
-                self.compile_cache = os.path.join(self.cache_dir,
-                                                  "jax-cache")
         store = ArtifactStore(self.cache_dir)
         store.write_manifest(self.manifest())
         ledger = JobLedger(
@@ -535,7 +532,14 @@ class CampaignRunner:
 
     def _spawn_worker(self, index: int, store_dir: str):
         """One worker subprocess (`python -m repro worker`) against the
-        shared store."""
+        shared store.
+
+        One process per chip: an accelerator belongs to the first
+        process that initializes jax on it, and a second one fails or
+        hangs.  So at most one live worker may use the accelerator; the
+        others start with ``JAX_PLATFORMS=cpu``.  When the worker that
+        held it has exited, the next spawned (or respawned) worker
+        takes it over."""
         import subprocess
         import sys
 
@@ -548,18 +552,35 @@ class CampaignRunner:
         env["PYTHONPATH"] = src_root + (
             os.pathsep + env["PYTHONPATH"]
             if env.get("PYTHONPATH") else "")
-        return subprocess.Popen(
+        holder = self._chip_holder
+        takes_chip = holder is None or holder.poll() is not None
+        if not takes_chip:
+            env["JAX_PLATFORMS"] = "cpu"
+        proc = subprocess.Popen(
             [sys.executable, "-m", "repro", "worker",
              "--store", store_dir,
              "--worker-id", f"w{index}-{os.getpid()}",
              "--lease-ttl", str(self.lease_ttl_s),
              "--max-retries", str(self.max_retries)],
             env=env)
+        if takes_chip:
+            self._chip_holder = proc
+        return proc
 
     def _run_process(self, jobs) -> CampaignResult:
         """Ledger-scheduled execution with worker processes + the
         :class:`CampaignSupervisor` reclaimer."""
+        import sys
+
         from repro.runtime.fault_tolerance import CampaignSupervisor
+        if "jax" in sys.modules and \
+                os.environ.get("JAX_PLATFORMS") != "cpu":
+            # a parent that has loaded jax may hold the accelerator,
+            # and the worker given it would then fail or hang
+            raise RuntimeError(
+                "scheduler='process' must be started from a process "
+                "that has not imported jax (or with JAX_PLATFORMS=cpu): "
+                "its workers need the accelerator")
         store, ledger, _ = self.prepare_store(jobs)
         already_done = {k for k, r in ledger.snapshot().items()
                         if r.state == "done"}
@@ -869,8 +890,8 @@ def main(argv=None):
                          "component)")
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
                     help="persistent jax compilation cache shared by "
-                         "every job/worker (--engine jax; defaults to "
-                         "<cache-dir>/jax-cache)")
+                         "every job/worker (default <checkout>/.jax-cache;"
+                         " JAX_COMPILATION_CACHE_DIR, when set, wins)")
     ap.add_argument("--out", default=None,
                     help="aggregate JSON path (default: "
                          "<cache-dir>/campaign_report.json)")
@@ -932,6 +953,12 @@ def main(argv=None):
         return {"jobs": [job.label for job in jobs],
                 "skipped": [list(s) for s in runner.skipped]}
 
+    if runner.scheduler == "thread":
+        # jobs run in this process: set its cache before the first jit
+        # (the process scheduler's parent stays jax-free; each worker
+        # configures its own from the manifest)
+        from repro.runtime import compile_cache
+        compile_cache.configure(runner.compile_cache)
     result = runner.run()
     agg = result.aggregate
 

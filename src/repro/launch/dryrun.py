@@ -173,7 +173,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         bytes_acc = max(rec["cost"].get("bytes accessed", 0.0),
                         hc["bytes"])
         rec["roofline"] = roofline.roofline_terms(
-            flops, bytes_acc, coll.total_bytes, chips)
+            flops, bytes_acc, coll.total_bytes, chips,
+            device_kind=roofline.V5E)      # the production mesh's chip
         mf = roofline.model_flops(cfg, shape)
         rec["model_flops"] = mf
         # flops is per-device (SPMD HLO); model_flops is whole-job

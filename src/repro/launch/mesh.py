@@ -8,7 +8,14 @@ from __future__ import annotations
 
 import jax
 
-from repro.distributed.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler), which the sharding rules in ``repro.distributed``
+    assume."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_shapes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

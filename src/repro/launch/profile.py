@@ -30,6 +30,7 @@ import json
 from repro.backends.systolic import GemmLayer
 from repro.core import ProfileSession
 from repro.devices import get_device_family
+from repro.runtime import compile_cache
 from repro.workloads import (get_workload, transformer_gemms,  # noqa: F401
                              transformer_program, tpu_step_workload)
 
@@ -195,6 +196,7 @@ def main(argv=None):
                     help="tiny built-in workload; pipeline smoke test")
     args = ap.parse_args(argv)
 
+    compile_cache.configure()       # before the first jit
     if args.dry_run:
         return _dry_run(args.backend, policy=args.policy,
                         engine=args.engine, csv_out=args.csv)

@@ -1,7 +1,7 @@
 """Roofline-term derivation from compiled XLA artifacts (deliverable g).
 
-Hardware model: TPU v5e - 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s per ICI link.
+Hardware model: the published peaks of one chip, looked up by jax
+``device_kind`` in :data:`CHIP_PEAKS` (the repo's one peak table).
 
   compute term    = HLO_FLOPs   / (chips * peak FLOP/s)
   memory term     = HLO_bytes   / (chips * HBM bandwidth)
@@ -36,9 +36,25 @@ import dataclasses
 import re
 
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+#: Published peaks of one chip, keyed by jax ``device_kind``.  Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+#: at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (200 GB/s over
+#: four ICI links, so 50 GB/s per link).
+V5E = "TPU v5 lite"
+CHIP_PEAKS = {
+    V5E: {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_link_bw": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; a kind not in the table is
+    an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1, "s16": 2, "u16": 2,
@@ -272,13 +288,15 @@ def hlo_cost(hlo_text: str) -> dict:
 
 def roofline_terms(flops: float, bytes_accessed: float,
                    coll_bytes: float, chips: int,
-                   per_device: bool = True) -> dict:
-    """Terms in seconds. When per_device=True the inputs are per-chip
-    (SPMD HLO) and `chips` is ignored for compute/memory."""
+                   per_device: bool = True, *, device_kind: str) -> dict:
+    """Terms in seconds on chips of ``device_kind``. When per_device=True
+    the inputs are per-chip (SPMD HLO) and `chips` is ignored for
+    compute/memory."""
+    peaks = chip_peaks(device_kind)
     div = 1 if per_device else chips
-    compute_s = flops / (div * PEAK_FLOPS)
-    memory_s = bytes_accessed / (div * HBM_BW)
-    collective_s = coll_bytes / (div * ICI_BW)
+    compute_s = flops / (div * peaks["flops_bf16"])
+    memory_s = bytes_accessed / (div * peaks["hbm_bw"])
+    collective_s = coll_bytes / (div * peaks["ici_link_bw"])
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dominant = max(terms, key=terms.get)

@@ -27,6 +27,7 @@ import json
 
 from repro.core import ProfileSession
 from repro.launch import parse_floats as _floats
+from repro.runtime import compile_cache
 from repro.sweep import DeviceGrid, FamilyGrid, SweepRunner
 
 
@@ -133,19 +134,20 @@ def main(argv=None):
                     help="composition evaluation backend (jax = jitted, "
                          "~1e-9 relative energy vs the numpy oracle)")
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent jax compilation cache (--engine "
-                         "jax): repeated sweeps warm-start their "
-                         "compiles from DIR")
+                    help="persistent jax compilation cache: repeated "
+                         "sweeps warm-start their compiles from DIR "
+                         "(default <checkout>/.jax-cache; "
+                         "JAX_COMPILATION_CACHE_DIR, when set, wins)")
     ap.add_argument("--out", default=None, help="JSON output path")
     ap.add_argument("--csv", default=None, help="CSV output path")
     ap.add_argument("--dry-run", action="store_true",
                     help="tiny built-in workload; sweep smoke test")
     args = ap.parse_args(argv)
 
+    compile_cache.configure(args.compile_cache)
     grid = _grid_from_args(args)
     runner = SweepRunner(grid, workers=args.workers, policy=args.policy,
-                         engine=args.engine,
-                         compile_cache=args.compile_cache)
+                         engine=args.engine)
     workload, cfg = _workload(args)
     geoms = _geometries(args)
     fam_tag = f" family={grid.family}" if args.family else ""

@@ -569,7 +569,6 @@ def _moe_local_dispatch(p, cfg, xt, logits, capacity_factor):
     """
     from jax.sharding import PartitionSpec as P
     from repro.distributed import sharding as shd
-    from repro.distributed.compat import shard_map
 
     mesh = shd.get_mesh()
     T, D = xt.shape
@@ -589,7 +588,7 @@ def _moe_local_dispatch(p, cfg, xt, logits, capacity_factor):
                                   E, K, C, e_base)
         return jax.lax.psum(y, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(batch_axes, None), P(batch_axes, None),
                   P("model", None, None), P("model", None, None),

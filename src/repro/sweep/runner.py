@@ -171,10 +171,7 @@ class SweepRunner:
 
     ``policy=`` selects the assignment policy for every evaluated
     candidate; ``engine=`` the evaluation backend (``"numpy"`` oracle
-    or jitted ``"jax"``).  ``compile_cache=`` points jax's persistent
-    compilation cache at a directory (ignored under ``engine="numpy"``)
-    so repeated runs — and campaign worker processes sharing the same
-    path — warm-start their compiles.  ``workers > 1``
+    or jitted ``"jax"``).  ``workers > 1``
     thread-parallelizes the outer (subpartition / geometry) loop;
     results are returned in deterministic submission order regardless
     of completion order.
@@ -182,22 +179,18 @@ class SweepRunner:
 
     def __init__(self, grid: DeviceGrid | None = None, *,
                  workers: int = 1, policy="refresh-free",
-                 engine="numpy", compile_cache: str | None = None):
+                 engine="numpy"):
         from repro.compose import get_policy
         self.grid = grid if grid is not None else DeviceGrid()
         self.workers = max(1, int(workers))
         self.policy = get_policy(policy)
         self.engine = engine
-        self.compile_cache = compile_cache
 
     # -- one subpartition ------------------------------------------------
     def run_stats(self, stats: SubpartitionStats, raw=None, *,
                   clock_hz: float = 1.0e9,
                   subpartition: str | None = None,
                   geometry: str | None = None) -> list:
-        if self.engine == "jax" and self.compile_cache:
-            from repro.compose.engine import configure_compile_cache
-            configure_compile_cache(self.compile_cache)
         cands = self.grid.candidates()
         comps = evaluate_candidates(cands, stats, raw=raw,
                                     clock_hz=clock_hz, policy=self.policy,

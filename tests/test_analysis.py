@@ -19,6 +19,7 @@ from repro.analysis import (AnalysisContext, AtomicWriteRule,
                             load_baseline, run_check,
                             update_schema_manifest, write_baseline)
 from repro.analysis.cli import main as check_main
+from repro.analysis.compat import JaxCompatRule
 
 FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "fixtures", "analysis")
@@ -105,6 +106,30 @@ def test_import_purity_exemption_is_shallow(tmp_path):
     assert eager[0].path == "repro/compose/executor.py"
     assert ("repro.compose.eager -> repro.compose.executor"
             in eager[0].message)
+
+
+# ---------------------------------------------------------------------------
+# jax-compat
+# ---------------------------------------------------------------------------
+
+def test_jax_compat_flags_direct_spellings_outside_the_shim(tmp_path):
+    pkg = tmp_path / "repro"
+    pkg.mkdir()
+    files = {
+        "compat.py": "import jax\n\ndef enable_x64():\n"
+                     "    return jax.enable_x64(True)\n",
+        "old.py": "from jax.experimental import enable_x64\n",
+        "new.py": "import jax\n\nwith jax.enable_x64(True):\n"
+                  "    pass\n",
+        "clean.py": "from repro.compat import enable_x64\n\n"
+                    "with enable_x64():\n    pass\n",
+    }
+    for name, src in files.items():
+        (pkg / name).write_text(src)
+    findings = JaxCompatRule().run(AnalysisContext(str(tmp_path)))
+    assert sorted((f.path, f.line) for f in findings) == [
+        ("repro/new.py", 3), ("repro/old.py", 1)]
+    assert all("repro.compat" in f.remediation for f in findings)
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,7 @@ def _run(code: str, n_devices: int = 8):
 def test_pipeline_parallel_matches_sequential():
     _run("""
 import jax, jax.numpy as jnp
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.distributed.pipeline import pipeline_forward
 mesh = make_mesh((4,), ("stage",))
 S, M, mb, d = 4, 6, 2, 8
@@ -44,7 +44,7 @@ def test_moe_local_dispatch_matches_global():
 import jax, jax.numpy as jnp, dataclasses
 from repro.configs import get_config
 from repro.distributed import sharding
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import layers as L
 mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_config("phi3_5_moe", smoke=True)
@@ -71,7 +71,7 @@ from repro.configs.base import ShapeCell
 from repro.distributed import sharding
 from repro.launch.steps import (abstract_params, make_optimizer,
                                 make_train_step)
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.api import batch_shardings, batch_specs, build
 mesh = make_mesh((4, 2), ("data", "model"))
 sharding.set_mesh(mesh)
@@ -99,12 +99,12 @@ def test_compressed_psum_shard_map():
     _run("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.distributed.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.optim.compression import compressed_psum
 mesh = make_mesh((4,), ("data",))
 x = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 13.0
-out = shard_map(lambda b: compressed_psum(b, "data"), mesh=mesh,
-                in_specs=P("data"), out_specs=P("data"))(x)
+out = jax.shard_map(lambda b: compressed_psum(b, "data"), mesh=mesh,
+                    in_specs=P("data"), out_specs=P("data"))(x)
 ref = jnp.tile(x.sum(0, keepdims=True) / 1.0, (4, 1)) * 0 + x.sum(0)
 # int8 quantization: tolerance = shared-scale resolution
 import numpy as np

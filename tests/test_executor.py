@@ -18,6 +18,7 @@ The locked contracts:
 
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -266,6 +267,9 @@ def test_process_campaign_workers_share_persistent_cache(tmp_path):
     assert tele["new_compiles"] > 0
     assert tele["persistent_cache_misses"] > 0
     assert tele["cache_dir"] == str(tmp_path / "jax-cache")
+    dev = jax.devices()[0]      # the worker ran on this host's backend
+    assert (tele["platform"], tele["device_kind"]) == (dev.platform,
+                                                       dev.device_kind)
 
     # a second campaign at a fresh artifact store re-executes the job
     # in a brand-new worker process; every compile must come out of the
