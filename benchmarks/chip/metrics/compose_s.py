@@ -1,0 +1,6 @@
+"""compose_s: host seconds per answer inside the benchmark's ``compose``
+span (see chipbench/program.py for what the span encloses)."""
+
+
+def read(ctx):
+    return ctx.span_per_request("compose")
