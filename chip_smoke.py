@@ -24,9 +24,12 @@ seq 128, 2 layers, line sample 8):
               (``TraceAccumulator``) on the same trace fed in chunks;
 7. placement - the arrays the jitted stages returned live on the TPU.
 
-Every phase prints its wall and compile seconds.  Any failure raises,
-and the exit code is non-zero.  The last line of standard output is a
-JSON object naming the device, printed only when every phase passed.
+At the end it prints, per program span name, the spans' count and
+their total and self seconds (``repro.runtime.obs``; compiles fall
+inside the spans that trigger them), and every counter's total.  Any
+failure raises, and the exit code is non-zero.  The last line of
+standard output is a JSON object naming the device, printed only when
+every phase passed.
 """
 
 from __future__ import annotations
@@ -34,7 +37,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(HERE, "src"))
@@ -42,7 +44,7 @@ sys.path.insert(0, os.path.join(HERE, "src"))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.runtime import compile_cache  # noqa: E402
+from repro.runtime import compile_cache, obs  # noqa: E402
 
 ARCH = "chatglm3_6b"
 SEQ = 128                       # the workload's default spec
@@ -51,31 +53,6 @@ POLICIES = ("refresh-free", "refresh-aware",
 ENERGY_RTOL = 1e-9              # docs/API.md "Accelerated engine"
 ORACLE_PREFIX = 1 << 17         # cachesim oracle: events of the L1 stream
 CHUNK_EVENTS = 1 << 20          # lifetime oracle: streaming chunk size
-
-
-class Phases:
-    """Wall and compile seconds per phase; compile time is read from
-    jax's own backend-compile duration events."""
-
-    def __init__(self):
-        self.rows = []
-        self._compile_s = 0.0
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-
-    def _on_duration(self, event, duration, **_kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self._compile_s += duration
-
-    def run(self, name, fn, *args):
-        c0, t0 = self._compile_s, time.perf_counter()
-        out = fn(*args)
-        wall = time.perf_counter() - t0
-        comp = self._compile_s - c0
-        self.rows.append((name, wall, comp))
-        print(f"[{name}] wall {wall:.3f} s, compile {comp:.3f} s",
-              flush=True)
-        return out
 
 
 class Spy:
@@ -263,11 +240,27 @@ def placement_phase(spies):
               f"{spy.name} returned arrays on {sorted(spy.platforms)}")
 
 
+def stage_totals():
+    """Per span name: count, total and self seconds; then counters."""
+    snap = obs.snapshot()
+    own = obs.self_ns(snap["spans"])
+    rows = {}
+    for s in snap["spans"]:
+        n, total, self_ = rows.get(s["name"], (0, 0, 0))
+        rows[s["name"]] = (n + 1, total + s["end_ns"] - s["start_ns"],
+                           self_ + own[s["id"]])
+    for name, (n, total, self_) in sorted(rows.items(),
+                                          key=lambda kv: -kv[1][1]):
+        print(f"stage {name:20s} n={n:4d} total_s={total * 1e-9:.3f} "
+              f"self_s={self_ * 1e-9:.3f}")
+    for name, v in sorted(snap["totals"].items()):
+        print(f"counter {name} {v}")
+
+
 def main() -> int:
     cache = compile_cache.configure()        # before the first jit
     print(f"compile cache: {cache}", flush=True)
-    phases = Phases()
-    device = phases.run("device", device_phase)
+    device = device_phase()
 
     from repro.backends import cachesim
     from repro.compose import executor
@@ -280,20 +273,19 @@ def main() -> int:
              # reached only by lifetimes without address groups
              Spy(executor, "_ra_ungrouped", required=False)]
 
-    session, workload, cfg = phases.run("profile", profile_phase)
-    phases.run("analyze", analyze_phase, session)
-    phases.run("compose", compose_phase, session)
-    phases.run("sweep", sweep_phase, session)
-    phases.run("oracle_cachesim", cachesim_oracle_phase, workload, cfg)
-    phases.run("oracle_lifetimes", lifetime_oracle_phase, session)
-    phases.run("placement", placement_phase, spies)
+    session, workload, cfg = profile_phase()
+    analyze_phase(session)
+    compose_phase(session)
+    sweep_phase(session)
+    cachesim_oracle_phase(workload, cfg)
+    lifetime_oracle_phase(session)
+    placement_phase(spies)
 
     stats = executor.compile_stats()
     print(f"compile: jit_entries={stats['jit_entries']} "
           f"persistent_cache_hits={stats['persistent_cache_hits']} "
           f"persistent_cache_misses={stats['persistent_cache_misses']}")
-    for name, wall, comp in phases.rows:
-        print(f"phase {name:17s} wall_s={wall:.3f} compile_s={comp:.3f}")
+    stage_totals()
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

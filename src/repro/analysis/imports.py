@@ -71,6 +71,8 @@ DEFAULT_CONTRACTS = (
     ImportContract("repro.__main__", ("jax", "numpy")),
     # a campaign parent resolves its workers' compile cache without jax
     ImportContract("repro.runtime.compile_cache", ("jax", "numpy")),
+    # the span recorder: compose.engine imports it at module level
+    ImportContract("repro.runtime.obs", ("jax", "numpy")),
 )
 
 

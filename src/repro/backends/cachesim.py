@@ -51,6 +51,7 @@ import numpy as np
 from repro.compat import enable_x64
 from repro.core.api import ProfileResult, register_backend
 from repro.core.trace import Trace, chunk_trace
+from repro.runtime import obs
 
 L1, L2 = 0, 1
 SUB_NAMES = ("L1", "L2")
@@ -91,12 +92,16 @@ def _simulate_cache(line_addr, is_write, n_sets, ways, write_allocate):
     evict_addr:  address of a line evicted by the fill (-1 if none/invalid)
     evict_dirty: evicted line was dirty (needs write-back)
     """
+    lines = np.asarray(line_addr, np.int64)
+    w = np.asarray(is_write, bool)
+    obs.count("h2d_bytes", lines.nbytes + w.nbytes)
     with enable_x64():
-        outs = _simulate_cache_scan(
-            jnp.asarray(np.asarray(line_addr, np.int64)),
-            jnp.asarray(np.asarray(is_write, bool)),
-            n_sets, ways, write_allocate)
-    return tuple(np.asarray(x) for x in outs)
+        outs = _simulate_cache_scan(jnp.asarray(lines, jnp.int64),
+                                    jnp.asarray(w, bool),
+                                    n_sets, ways, write_allocate)
+    outs = tuple(np.asarray(x) for x in outs)
+    obs.count("d2h_bytes", sum(x.nbytes for x in outs))
+    return outs
 
 
 @partial(jax.jit, static_argnames=("n_sets", "ways", "write_allocate"))
@@ -228,7 +233,7 @@ _MAX_PAD_RATIO = 8
 
 
 def _simulate_cache_set_parallel(line_addr, is_write, n_sets, ways,
-                                 write_allocate):
+                                 write_allocate, *, level="L1"):
     """Set-parallel replay of one cache level; host in/out in stream order.
 
     Partitions the stream by set index (stable, so each set keeps its
@@ -237,6 +242,9 @@ def _simulate_cache_set_parallel(line_addr, is_write, n_sets, ways,
     evict_dirty) bit-for-bit identical to the scalar oracle's.  Streams
     skewed enough that the set-partitioned layout is mostly padding run
     through the scalar scan instead (same results, better complexity).
+    The three steps are the spans ``cachesim.partition``,
+    ``cachesim.scan`` and ``cachesim.gather``, each with attribute
+    ``level``.
     """
     lines = np.asarray(line_addr, np.int64)
     w = np.asarray(is_write, bool)
@@ -250,47 +258,55 @@ def _simulate_cache_set_parallel(line_addr, is_write, n_sets, ways,
             f"(got [{int(lines.min())}, {int(lines.max())}]); that is "
             "byte addresses below 2^66 at 128-byte lines")
 
-    set_dt = np.uint8 if n_sets <= 256 else np.uint32
-    set_idx = (lines % n_sets).astype(set_dt)
-    counts64 = np.bincount(set_idx, minlength=n_sets)
-    L = int(counts64.max())
-    if n_sets * L > max(_MAX_PAD_RATIO * n, 4096):
-        return _simulate_cache(lines, w, n_sets, ways, write_allocate)
+    with obs.span("cachesim.partition", level=level):
+        set_dt = np.uint8 if n_sets <= 256 else np.uint32
+        set_idx = (lines % n_sets).astype(set_dt)
+        counts64 = np.bincount(set_idx, minlength=n_sets)
+        L = int(counts64.max())
+        skewed = n_sets * L > max(_MAX_PAD_RATIO * n, 4096)
+        if not skewed:
+            # Round the padded width up to a power of two: the jitted
+            # scan is shape-specialized, so quantizing L makes workload
+            # sweeps reuse the XLA compile cache instead of recompiling
+            # per stream (the counts mask already neutralizes padding
+            # lanes, so results are unchanged).
+            L = 1 << (L - 1).bit_length()
 
-    # Round the padded width up to a power of two: the jitted scan is
-    # shape-specialized, so quantizing L makes workload sweeps reuse the
-    # XLA compile cache instead of recompiling per stream (the counts
-    # mask already neutralizes padding lanes, so results are unchanged).
-    L = 1 << (L - 1).bit_length()
+            order = np.argsort(set_idx, kind="stable")
+            counts = counts64.astype(np.int32)
+            starts = np.zeros(n_sets, np.int64)
+            starts[1:] = np.cumsum(counts64)[:-1]
+            rows = set_idx[order].astype(np.int64)
+            slots = np.arange(n, dtype=np.int64) - starts[rows]
 
-    order = np.argsort(set_idx, kind="stable")
-    counts = counts64.astype(np.int32)
-    starts = np.zeros(n_sets, np.int64)
-    starts[1:] = np.cumsum(counts64)[:-1]
-    rows = set_idx[order].astype(np.int64)
-    slots = np.arange(n, dtype=np.int64) - starts[rows]
+            packed = np.zeros((n_sets, L), np.int64)
+            packed[rows, slots] = lines[order] * 2 + w[order]
+            flat_pos = np.empty(n, np.int64)
+            flat_pos[order] = slots * n_sets + rows   # (L, n_sets) row-major
 
-    packed = np.zeros((n_sets, L), np.int64)
-    packed[rows, slots] = lines[order] * 2 + w[order]
-    flat_pos = np.empty(n, np.int64)
-    flat_pos[order] = slots * n_sets + rows       # (L, n_sets) row-major
+    with obs.span("cachesim.scan", level=level):
+        if skewed:
+            return _simulate_cache(lines, w, n_sets, ways, write_allocate)
+        obs.count("h2d_bytes", packed.nbytes + counts.nbytes)
+        with enable_x64():
+            out_p = np.asarray(_simulate_cache_sets(
+                jnp.asarray(packed), jnp.asarray(counts),
+                ways, write_allocate))
+        obs.count("d2h_bytes", out_p.nbytes)
 
-    with enable_x64():
-        out_p = np.asarray(_simulate_cache_sets(
-            jnp.asarray(packed), jnp.asarray(counts),
-            ways, write_allocate))
-    out = out_p.reshape(-1)[flat_pos]             # back to stream order
-
-    return ((out & 1).astype(bool), ((out >> 1) & 1).astype(bool),
-            (out >> 3) - 1, ((out >> 2) & 1).astype(bool))
+    with obs.span("cachesim.gather", level=level):
+        out = out_p.reshape(-1)[flat_pos]         # back to stream order
+        return ((out & 1).astype(bool), ((out >> 1) & 1).astype(bool),
+                (out >> 3) - 1, ((out >> 2) & 1).astype(bool))
 
 
 def _simulate_level(lines, w, level: CacheConfig, write_allocate: bool,
-                    simulator: str):
-    """Dispatch one cache level to the selected simulator (host arrays)."""
+                    simulator: str, name: str = "L1"):
+    """Dispatch one cache level (``name``, the span attribute) to the
+    selected simulator (host arrays)."""
     if simulator == "set_parallel":
         return _simulate_cache_set_parallel(
-            lines, w, level.n_sets, level.ways, write_allocate)
+            lines, w, level.n_sets, level.ways, write_allocate, level=name)
     if simulator == "scalar":
         return _simulate_cache(lines, w, level.n_sets, level.ways,
                                write_allocate)
@@ -311,41 +327,44 @@ def simulate_hierarchy(
     w = np.asarray(is_write, bool)
 
     hit1, fill1, ev_addr, ev_dirty = _simulate_level(
-        lines, w, cfg.l1, cfg.write_allocate, cfg.simulator)
+        lines, w, cfg.l1, cfg.write_allocate, cfg.simulator, SUB_NAMES[L1])
 
     # --- compose the L2 access stream, preserving time order -------------
-    l2_t, l2_a, l2_w = [], [], []
-    # fills: L1 fetched the line from L2 (read)
-    l2_t.append(t[fill1] + cfg.l2_latency)
-    l2_a.append(lines[fill1])
-    l2_w.append(np.zeros(int(fill1.sum()), bool))
-    # dirty evictions: write-back to L2
-    m = ev_dirty & (ev_addr >= 0)
-    l2_t.append(t[m] + cfg.l2_latency)
-    l2_a.append(ev_addr[m].astype(np.int64))
-    l2_w.append(np.ones(int(m.sum()), bool))
-    # no-write-allocate: write misses bypass to L2
-    if not cfg.write_allocate:
-        m = w & ~hit1
+    with obs.span("cachesim.l2_stream"):
+        l2_t, l2_a, l2_w = [], [], []
+        # fills: L1 fetched the line from L2 (read)
+        l2_t.append(t[fill1] + cfg.l2_latency)
+        l2_a.append(lines[fill1])
+        l2_w.append(np.zeros(int(fill1.sum()), bool))
+        # dirty evictions: write-back to L2
+        m = ev_dirty & (ev_addr >= 0)
         l2_t.append(t[m] + cfg.l2_latency)
-        l2_a.append(lines[m])
+        l2_a.append(ev_addr[m].astype(np.int64))
         l2_w.append(np.ones(int(m.sum()), bool))
-    l2_t = np.concatenate(l2_t)
-    l2_a = np.concatenate(l2_a)
-    l2_w = np.concatenate(l2_w)
-    order = np.argsort(l2_t, kind="stable")
-    l2_t, l2_a, l2_w = l2_t[order], l2_a[order], l2_w[order]
+        # no-write-allocate: write misses bypass to L2
+        if not cfg.write_allocate:
+            m = w & ~hit1
+            l2_t.append(t[m] + cfg.l2_latency)
+            l2_a.append(lines[m])
+            l2_w.append(np.ones(int(m.sum()), bool))
+        l2_t = np.concatenate(l2_t)
+        l2_a = np.concatenate(l2_a)
+        l2_w = np.concatenate(l2_w)
+        order = np.argsort(l2_t, kind="stable")
+        l2_t, l2_a, l2_w = l2_t[order], l2_a[order], l2_w[order]
 
     hit2 = _simulate_level(
-        l2_a, l2_w, cfg.l2, cfg.write_allocate, cfg.simulator)[0]
+        l2_a, l2_w, cfg.l2, cfg.write_allocate, cfg.simulator,
+        SUB_NAMES[L2])[0]
 
-    times = np.concatenate([t, l2_t])
-    addrs = np.concatenate([lines, l2_a])
-    writes = np.concatenate([w, l2_w])
-    hits = np.concatenate([np.asarray(hit1), np.asarray(hit2)])
-    subs = np.concatenate([np.zeros(len(t), np.int32),
-                           np.ones(len(l2_t), np.int32)])
-    order = np.argsort(times, kind="stable")
+    with obs.span("cachesim.merge"):
+        times = np.concatenate([t, l2_t])
+        addrs = np.concatenate([lines, l2_a])
+        writes = np.concatenate([w, l2_w])
+        hits = np.concatenate([np.asarray(hit1), np.asarray(hit2)])
+        subs = np.concatenate([np.zeros(len(t), np.int32),
+                               np.ones(len(l2_t), np.int32)])
+        order = np.argsort(times, kind="stable")
     return Trace(
         time_cycles=times[order], addr=addrs[order], is_write=writes[order],
         hit=hits[order], subpartition=subs[order],

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core.api import ProfileResult, register_backend
 from repro.core.trace import Trace, chunk_trace
+from repro.runtime import obs
 
 LINE_BYTES = 128
 FLOPS_PER_CYCLE = 1.0e5          # ~100 TFLOP/s at 1 GHz
@@ -269,6 +270,7 @@ class OpStreamBackend:
 # Workload lowerings (paper Table 5 analogues, driven by framework configs)
 # --------------------------------------------------------------------------
 
+@obs.span("opstream.lower")
 def transformer_ops(
     sb: StreamBuilder,
     d_model: int,

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core.api import ProfileResult, register_backend
 from repro.core.trace import Trace, chunk_trace
+from repro.runtime import obs
 
 IFMAP, FILTER, OFMAP = 0, 1, 2
 SUB_NAMES = ("ifmap", "filter", "ofmap")
@@ -237,6 +238,7 @@ def simulate_layer(b, bufs, cfg: SystolicConfig, layer: GemmLayer,
     return t
 
 
+@obs.span("systolic.simulate")
 def simulate(layers: Sequence[GemmLayer],
              cfg: SystolicConfig) -> tuple[Trace, list[dict]]:
     """Simulate a workload; returns (trace, per-layer kernel stats).

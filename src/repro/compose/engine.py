@@ -58,6 +58,7 @@ import numpy as np
 from repro.compose.policies import (AddressGroups, AssignmentPolicy,
                                     PolicyBatch, get_policy)
 from repro.compose.types import Composition
+from repro.runtime import obs
 # repro.core is imported lazily (function scope): executing its package
 # __init__ pulls the jax-backed lifetime stack, and this module is part
 # of the repro.compose jax-free-at-import contract (`repro check`
@@ -104,17 +105,21 @@ def address_groups(raw, clock_hz: float) -> AddressGroups:
     hit = _groups_memo.get(key)
     if hit is not None and hit[0]() is raw and hit[1] == clock_hz:
         return hit[2]
-    valid = np.asarray(raw.valid)
-    addr = np.asarray(raw.addr)[valid]
-    lt_cyc = np.asarray(raw.lifetime_cycles)[valid]
-    order = np.argsort(addr, kind="stable")
-    addr_s, lt_sorted = addr[order], lt_cyc[order]
-    new = np.concatenate([[True], addr_s[1:] != addr_s[:-1]])
-    grp = np.cumsum(new) - 1
-    max_lt = np.zeros(grp[-1] + 1 if len(grp) else 0)
-    np.maximum.at(max_lt, grp, lt_sorted)
-    groups = AddressGroups(order=order, starts=np.flatnonzero(new),
-                           max_lt_s=max_lt / clock_hz)
+    with obs.span("compose.address_groups"):
+        valid = np.asarray(raw.valid)
+        if not isinstance(raw.addr, np.ndarray):
+            # the one device array here that frontend.stats did not pull
+            obs.count("d2h_bytes", raw.addr.nbytes)
+        addr = np.asarray(raw.addr)[valid]
+        lt_cyc = np.asarray(raw.lifetime_cycles)[valid]
+        order = np.argsort(addr, kind="stable")
+        addr_s, lt_sorted = addr[order], lt_cyc[order]
+        new = np.concatenate([[True], addr_s[1:] != addr_s[:-1]])
+        grp = np.cumsum(new) - 1
+        max_lt = np.zeros(grp[-1] + 1 if len(grp) else 0)
+        np.maximum.at(max_lt, grp, lt_sorted)
+        groups = AddressGroups(order=order, starts=np.flatnonzero(new),
+                               max_lt_s=max_lt / clock_hz)
     try:
         ref = weakref.ref(raw, lambda _, k=key: _groups_memo.pop(k, None))
         _groups_memo[key] = (ref, clock_hz, groups)
@@ -215,7 +220,8 @@ def sorted_trace_view(stats: SubpartitionStats, raw,
             and (hit[1] is None or hit[1]() is raw)
             and hit[2] == clock_hz):
         return hit[3]
-    view = _build_trace_view(stats, raw, clock_hz)
+    with obs.span("compose.trace_view", subpartition=stats.name):
+        view = _build_trace_view(stats, raw, clock_hz)
     try:
         cb = lambda _, k=key: _view_memo.pop(k, None)  # noqa: E731
         sref = weakref.ref(stats, cb)
@@ -362,6 +368,8 @@ def evaluate(
     ``engine`` selects the chunk executor: ``"numpy"`` (default,
     bit-for-bit seed contract) or ``"jax"`` (fused jitted kernels,
     ~1e-9-relative agreement; see :mod:`repro.compose.jax_engine`).
+    The call is the span ``compose.evaluate``, its host candidate loop
+    ``compose.epilogue`` (:mod:`repro.runtime.obs`).
     """
     if engine not in ("numpy", "jax"):
         raise ValueError(
@@ -378,102 +386,105 @@ def evaluate(
         return []
     _validate_sets(sets)
 
-    # Deterministic device order: cheapest refresh-free access energy
-    # first, name-tie-broken; SRAM (infinite retention) is the usual
-    # last resort.
-    sorted_devs = [sorted(ds, key=_device_sort_key) for ds in sets]
+    with obs.span("compose.evaluate", subpartition=stats.name,
+                  policy=pol.name, candidates=len(sets)):
+        # Deterministic device order: cheapest refresh-free access energy
+        # first, name-tie-broken; SRAM (infinite retention) is the usual
+        # last resort.
+        sorted_devs = [sorted(ds, key=_device_sort_key) for ds in sets]
 
-    lt = stats.lifetimes_s
-    if len(lt) == 0:
-        return [_empty_composition(stats, devs, ds, pol)
-                for devs, ds in zip(sorted_devs, sets)]
+        lt = stats.lifetimes_s
+        if len(lt) == 0:
+            return [_empty_composition(stats, devs, ds, pol)
+                    for devs, ds in zip(sorted_devs, sets)]
 
-    bits = stats.lifetime_bits
-    reads = stats.accesses_per_lifetime - 1.0
-    groups = address_groups(raw, clock_hz) if raw is not None else None
-    # capacity fallback when ungrouped: bits-weighted per-lifetime fractions
-    w = bits / bits.sum() if groups is None else None
+        bits = stats.lifetime_bits
+        reads = stats.accesses_per_lifetime - 1.0
+        groups = address_groups(raw, clock_hz) if raw is not None else None
+        # capacity fallback when ungrouped: bits-weighted per-lifetime fractions
+        w = bits / bits.sum() if groups is None else None
 
-    # Monolithic baselines depend on (stats, device); memoized by device
-    # — SRAM is shared by every candidate, scale variants recur.
-    from repro.core.frontend import analyze_energy
-    mono_cache: dict = {}
+        # Monolithic baselines depend on (stats, device); memoized by device
+        # — SRAM is shared by every candidate, scale variants recur.
+        from repro.core.frontend import analyze_energy
+        mono_cache: dict = {}
 
-    def mono_energy(d: DeviceModel) -> float:
-        if d not in mono_cache:
-            mono_cache[d] = analyze_energy(stats, d)[0]
-        return mono_cache[d]
+        def mono_energy(d: DeviceModel) -> float:
+            if d not in mono_cache:
+                mono_cache[d] = analyze_energy(stats, d)[0]
+            return mono_cache[d]
 
-    n_dev = np.array([len(ds) for ds in sorted_devs])
-    d_max = int(n_dev.max())
+        n_dev = np.array([len(ds) for ds in sorted_devs])
+        d_max = int(n_dev.max())
 
-    # Padded device matrices ([candidate, device], small): -inf
-    # retention never fits, +inf energies never win an argmin.
-    ret = np.full((len(sets), d_max), -np.inf)
-    read_fj = np.full((len(sets), d_max), np.inf)
-    write_fj = np.full((len(sets), d_max), np.inf)
-    for ci, devs in enumerate(sorted_devs):
-        ret[ci, :len(devs)] = [d.retention_at(stats.write_freq_hz)
-                               for d in devs]
-        read_fj[ci, :len(devs)] = [d.read_fj_per_bit for d in devs]
-        write_fj[ci, :len(devs)] = [d.write_fj_per_bit for d in devs]
-    pad = np.arange(d_max)[None, :] >= n_dev[:, None]
-    fallback = (n_dev - 1)[:, None]
+        # Padded device matrices ([candidate, device], small): -inf
+        # retention never fits, +inf energies never win an argmin.
+        ret = np.full((len(sets), d_max), -np.inf)
+        read_fj = np.full((len(sets), d_max), np.inf)
+        write_fj = np.full((len(sets), d_max), np.inf)
+        for ci, devs in enumerate(sorted_devs):
+            ret[ci, :len(devs)] = [d.retention_at(stats.write_freq_hz)
+                                   for d in devs]
+            read_fj[ci, :len(devs)] = [d.read_fj_per_bit for d in devs]
+            write_fj[ci, :len(devs)] = [d.write_fj_per_bit for d in devs]
+        pad = np.arange(d_max)[None, :] >= n_dev[:, None]
+        fallback = (n_dev - 1)[:, None]
 
-    e_all = f_all = None
-    if engine == "jax":
-        # The fused executor takes the whole grid at once: it buckets
-        # candidates internally (vmapped batches / fixed slabs), reuses
-        # the memoized trace view's device-resident twin, and returns
-        # the full [C] energy / [C, D] fraction arrays — the chunk loop
-        # below only runs the host epilogue.
-        from repro.compose import executor  # lazy: keeps this module jax-free
-        view = sorted_trace_view(stats, raw, clock_hz)
-        full = PolicyBatch(
-            devs=tuple(sorted_devs), ret_s=ret, read_fj=read_fj,
-            write_fj=write_fj, pad=pad, fallback=fallback,
-            lt_s=lt, reads=reads, bits=bits, groups=groups)
-        e_all, f_all = executor.run_batch(pol, full, view)
-
-    chunk = max(1, _MAX_BROADCAST_BYTES
-                // max(1, d_max * len(lt) * pol.broadcast_itemsize))
-    out = []
-    for lo in range(0, len(sets), chunk):
-        hi = min(lo + chunk, len(sets))
-        if e_all is not None:
-            asg = None
-        else:
-            batch = PolicyBatch(
-                devs=tuple(sorted_devs[lo:hi]), ret_s=ret[lo:hi],
-                read_fj=read_fj[lo:hi], write_fj=write_fj[lo:hi],
-                pad=pad[lo:hi], fallback=fallback[lo:hi],
+        e_all = f_all = None
+        if engine == "jax":
+            # The fused executor takes the whole grid at once: it buckets
+            # candidates internally (vmapped batches / fixed slabs), reuses
+            # the memoized trace view's device-resident twin, and returns
+            # the full [C] energy / [C, D] fraction arrays — the chunk loop
+            # below only runs the host epilogue.
+            from repro.compose import executor  # lazy: keeps this module jax-free
+            view = sorted_trace_view(stats, raw, clock_hz)
+            full = PolicyBatch(
+                devs=tuple(sorted_devs), ret_s=ret, read_fj=read_fj,
+                write_fj=write_fj, pad=pad, fallback=fallback,
                 lt_s=lt, reads=reads, bits=bits, groups=groups)
-            asg = pol.assign(batch)
-        for ci in range(lo, hi):
-            devs, dset = sorted_devs[ci], sets[ci]
-            if asg is None:
-                energy = float(e_all[ci])
-                frac = f_all[ci, :len(devs)].copy()
-            else:
-                energy, frac = _numpy_candidate(
-                    asg, ci - lo, devs, reads, bits, w)
-            frac, quant = pol.capacity(frac, devs)
-            mono = {d.name: mono_energy(d) for d in dset}
-            sram_e = mono["SRAM"]
-            area_um2, area_ratio = _area_accounting(
-                devs, frac, stats.capacity_bits)
-            out.append(Composition(
-                devices=tuple(d.name for d in devs),
-                capacity_fractions=frac,
-                energy_j=energy,
-                energy_vs_sram=energy / sram_e if sram_e > 0 else math.nan,
-                monolithic_energy_j=mono,
-                area_um2=area_um2,
-                area_vs_sram=area_ratio,
-                policy=pol.name,
-                quantization=quant,
-            ))
-    return out
+            e_all, f_all = executor.run_batch(pol, full, view)
+
+        with obs.span("compose.epilogue"):
+            chunk = max(1, _MAX_BROADCAST_BYTES
+                        // max(1, d_max * len(lt) * pol.broadcast_itemsize))
+            out = []
+            for lo in range(0, len(sets), chunk):
+                hi = min(lo + chunk, len(sets))
+                if e_all is not None:
+                    asg = None
+                else:
+                    batch = PolicyBatch(
+                        devs=tuple(sorted_devs[lo:hi]), ret_s=ret[lo:hi],
+                        read_fj=read_fj[lo:hi], write_fj=write_fj[lo:hi],
+                        pad=pad[lo:hi], fallback=fallback[lo:hi],
+                        lt_s=lt, reads=reads, bits=bits, groups=groups)
+                    asg = pol.assign(batch)
+                for ci in range(lo, hi):
+                    devs, dset = sorted_devs[ci], sets[ci]
+                    if asg is None:
+                        energy = float(e_all[ci])
+                        frac = f_all[ci, :len(devs)].copy()
+                    else:
+                        energy, frac = _numpy_candidate(
+                            asg, ci - lo, devs, reads, bits, w)
+                    frac, quant = pol.capacity(frac, devs)
+                    mono = {d.name: mono_energy(d) for d in dset}
+                    sram_e = mono["SRAM"]
+                    area_um2, area_ratio = _area_accounting(
+                        devs, frac, stats.capacity_bits)
+                    out.append(Composition(
+                        devices=tuple(d.name for d in devs),
+                        capacity_fractions=frac,
+                        energy_j=energy,
+                        energy_vs_sram=energy / sram_e if sram_e > 0 else math.nan,
+                        monolithic_energy_j=mono,
+                        area_um2=area_um2,
+                        area_vs_sram=area_ratio,
+                        policy=pol.name,
+                        quantization=quant,
+                    ))
+        return out
 
 
 def compose(

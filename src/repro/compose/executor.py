@@ -63,6 +63,7 @@ level and is exempt from the ``repro.compose`` import-purity contract
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import weakref
 
@@ -74,6 +75,7 @@ from repro.compat import enable_x64
 from repro.compose.jax_engine import (_DISPATCH_LOCK, _base_policy,
                                       _host_weighted_fracs, supports)
 from repro.compose.policies import RefreshFreePolicy
+from repro.runtime import obs
 
 _F64 = np.float64
 
@@ -136,13 +138,29 @@ def compile_stats() -> dict:
 
 
 # ---------------------------------------------------------------------------
+# host <-> device, counted (repro.runtime.obs)
+# ---------------------------------------------------------------------------
+
+def _put(a: np.ndarray):
+    obs.count("h2d_bytes", a.nbytes)
+    return jnp.asarray(a)
+
+
+def _pull(x) -> np.ndarray:
+    obs.count("d2h_bytes", x.nbytes)
+    return np.asarray(x)
+
+
+# ---------------------------------------------------------------------------
 # device-resident trace state
 # ---------------------------------------------------------------------------
 
 class _TraceResidence:
     """Device-resident, bucket-padded twins of one subpartition's
     ``sorted_trace_view`` arrays, built lazily per policy family and
-    reused across every candidate batch, policy, and geometry."""
+    reused across every candidate batch, policy, and geometry.  Each
+    set's build (host padding and upload) is the span
+    ``executor.residence``, attribute ``arrays``."""
 
     def __init__(self, view):
         self.n_lt = int(view.n_lt)
@@ -159,45 +177,46 @@ class _TraceResidence:
         past the real extent read exact totals), and the +inf-padded
         sorted per-address max lifetimes."""
         if self._value is None:
-            lt = np.full(self.L_pad, np.inf)
-            lt[:self.n_lt] = view.lt_sorted
-            pb = np.empty(self.L_pad + 1)
-            pb[:self.n_lt + 1] = view.prefix_bits
-            pb[self.n_lt + 1:] = view.prefix_bits[-1]
-            prb = np.empty(self.L_pad + 1)
-            prb[:self.n_lt + 1] = view.prefix_read_bits
-            prb[self.n_lt + 1:] = view.prefix_read_bits[-1]
-            ml = np.full(self.A_pad, np.inf)
-            if view.maxlt_sorted is not None:
-                ml[:self.n_addr] = view.maxlt_sorted
-            self._value = tuple(jnp.asarray(a, _F64)
-                                for a in (lt, pb, prb, ml))
+            with obs.span("executor.residence", arrays="value"):
+                lt = np.full(self.L_pad, np.inf)
+                lt[:self.n_lt] = view.lt_sorted
+                pb = np.empty(self.L_pad + 1)
+                pb[:self.n_lt + 1] = view.prefix_bits
+                pb[self.n_lt + 1:] = view.prefix_bits[-1]
+                prb = np.empty(self.L_pad + 1)
+                prb[:self.n_lt + 1] = view.prefix_read_bits
+                prb[self.n_lt + 1:] = view.prefix_read_bits[-1]
+                ml = np.full(self.A_pad, np.inf)
+                if view.maxlt_sorted is not None:
+                    ml[:self.n_addr] = view.maxlt_sorted
+                self._value = tuple(map(_put, (lt, pb, prb, ml)))
         return self._value
 
     def addr_sorted(self, view):
         """Zero-padded address-sorted lifetime arrays + segment ids —
         padding lands in segment 0 and contributes exact zeros."""
         if self._addr is None:
-            def zpad(a):
-                out = np.zeros(self.L_pad)
-                out[:self.n_lt] = a
-                return jnp.asarray(out, _F64)
-            seg = np.zeros(self.L_pad, np.int32)
-            seg[:self.n_lt] = view.seg
-            self._addr = (zpad(view.lt_addr), zpad(view.reads_addr),
-                          zpad(view.bits_addr), jnp.asarray(seg))
+            with obs.span("executor.residence", arrays="addr"):
+                seg = np.zeros(self.L_pad, np.int32)
+                seg[:self.n_lt] = view.seg
+                self._addr = tuple(map(_put, (
+                    self._zpad(view.lt_addr), self._zpad(view.reads_addr),
+                    self._zpad(view.bits_addr), seg)))
         return self._addr
 
     def original(self, lt, reads, bits):
         """Zero-padded original-order arrays (ungrouped refresh-aware:
         the per-lifetime picks must come back in oracle element order)."""
         if self._orig is None:
-            def zpad(a):
-                out = np.zeros(self.L_pad)
-                out[:self.n_lt] = a
-                return jnp.asarray(out, _F64)
-            self._orig = (zpad(lt), zpad(reads), zpad(bits))
+            with obs.span("executor.residence", arrays="original"):
+                self._orig = tuple(map(_put, (
+                    self._zpad(lt), self._zpad(reads), self._zpad(bits))))
         return self._orig
+
+    def _zpad(self, a):
+        out = np.zeros(self.L_pad)
+        out[:self.n_lt] = a
+        return out
 
 
 # id(view) -> (weakref(view), residence); the weakref guards id reuse
@@ -343,6 +362,27 @@ def _pad_cd(a: np.ndarray, c_pad: int, d_pad: int, fill) -> np.ndarray:
     return out
 
 
+@contextlib.contextmanager
+def _slab(kernel: str, rows: int, real_rows: int):
+    """The span ``executor.slab`` of one kernel dispatch: inputs up,
+    the kernel, outputs down, so the slab's device work lies inside it;
+    ``slab_rows`` counts its candidate rows and ``slab_real_rows`` those
+    that are not padding."""
+    with obs.span("executor.slab", kernel=kernel):
+        obs.count("slab_rows", rows)
+        obs.count("slab_real_rows", real_rows)
+        yield
+
+
+def _slab_inputs(batch, lo: int, hi: int, rows: int, d_pad: int):
+    """Candidates ``lo:hi`` of the batch's device matrices, padded to
+    ``[rows, d_pad]`` and uploaded."""
+    return (_put(_pad_cd(batch.ret_s[lo:hi], rows, d_pad, -np.inf)),
+            _put(_pad_cd(batch.read_fj[lo:hi], rows, d_pad, np.inf)),
+            _put(_pad_cd(batch.write_fj[lo:hi], rows, d_pad, np.inf)),
+            _put(_pad_cd(batch.pad[lo:hi], rows, d_pad, True)))
+
+
 def _rf_ungrouped_host_fracs(batch, d_max: int) -> np.ndarray:
     """raw=None capacity: reconstruct the per-lifetime first-fit picks
     on the host (``searchsorted`` into each candidate's retention
@@ -377,27 +417,24 @@ def run_batch(pol, batch, view):
     with _DISPATCH_LOCK, enable_x64():
         res = _residence_for(view)
         d_pad = _next_pow2(d_max, _D_MIN)
-        n_lt = jnp.asarray(np.int64(res.n_lt))
-        n_addr = jnp.asarray(np.int64(res.n_addr))
+        n_lt = _put(np.int64(res.n_lt))
+        n_addr = _put(np.int64(res.n_addr))
         if isinstance(base, RefreshFreePolicy):
             c_pad = _next_pow2(C, _C_MIN)
-            ret = jnp.asarray(_pad_cd(batch.ret_s, c_pad, d_pad,
-                                      -np.inf), _F64)
-            rfj = jnp.asarray(_pad_cd(batch.read_fj, c_pad, d_pad,
-                                      np.inf), _F64)
-            wfj = jnp.asarray(_pad_cd(batch.write_fj, c_pad, d_pad,
-                                      np.inf), _F64)
-            padm = jnp.asarray(_pad_cd(batch.pad, c_pad, d_pad, True))
+            lt_s, pbits, prbits, ml = res.value_sorted(view)
             fb = np.zeros(c_pad, np.int64)
             fb[:C] = np.asarray(batch.fallback)[:, 0]
-            lt_s, pbits, prbits, ml = res.value_sorted(view)
-            e, cnt = _rf_fused(ret, rfj, wfj, padm, jnp.asarray(fb),
-                               lt_s, pbits, prbits, ml, n_lt, n_addr)
-            energy = np.asarray(e)[:C]
+            with _slab("rf_fused", c_pad, C):
+                ret, rfj, wfj, padm = _slab_inputs(batch, 0, C, c_pad,
+                                                   d_pad)
+                e, cnt = _rf_fused(ret, rfj, wfj, padm, _put(fb),
+                                   lt_s, pbits, prbits, ml, n_lt, n_addr)
+                e, cnt = _pull(e), _pull(cnt)
+            energy = e[:C]
             if grouped:
                 # integer counts / A on the host: correctly rounded,
                 # bit-identical to the oracle's bincount / A
-                frac = np.asarray(cnt)[:C, :d_max] / view.n_addr
+                frac = cnt[:C, :d_max] / view.n_addr
             else:
                 frac = _rf_ungrouped_host_fracs(batch, d_max)
             return energy, frac
@@ -413,28 +450,26 @@ def run_batch(pol, batch, view):
             lt_o, reads_o, bits_o = res.original(
                 batch.lt_s, batch.reads, batch.bits)
             bits_host = np.asarray(batch.bits, _F64)
+        kernel = "ra_grouped" if grouped else "ra_ungrouped"
         for lo in range(0, C, slab):
             hi = min(lo + slab, C)
-            ret = jnp.asarray(_pad_cd(batch.ret_s[lo:hi], slab, d_pad,
-                                      -np.inf), _F64)
-            rfj = jnp.asarray(_pad_cd(batch.read_fj[lo:hi], slab, d_pad,
-                                      np.inf), _F64)
-            wfj = jnp.asarray(_pad_cd(batch.write_fj[lo:hi], slab,
-                                      d_pad, np.inf), _F64)
-            padm = jnp.asarray(_pad_cd(batch.pad[lo:hi], slab, d_pad,
-                                       True))
+            with _slab(kernel, slab, hi - lo):
+                ret, rfj, wfj, padm = _slab_inputs(batch, lo, hi, slab,
+                                                   d_pad)
+                # out: pick counts [slab, D] when grouped, else each
+                # lifetime's pick [slab, L]
+                if grouped:
+                    e, out = _ra_grouped(ret, rfj, wfj, padm, lt_a,
+                                         reads_a, bits_a, seg, n_addr,
+                                         n_seg=res.A_pad)
+                else:
+                    e, out = _ra_ungrouped(ret, rfj, wfj, padm, lt_o,
+                                           reads_o, bits_o)
+                e, out = _pull(e), _pull(out)
+            energy[lo:hi] = e[:hi - lo]
             if grouped:
-                e, cnt = _ra_grouped(ret, rfj, wfj, padm, lt_a, reads_a,
-                                     bits_a, seg, n_addr,
-                                     n_seg=res.A_pad)
-                energy[lo:hi] = np.asarray(e)[:hi - lo]
-                frac[lo:hi] = (np.asarray(cnt)[:hi - lo, :d_max]
-                               / view.n_addr)
+                frac[lo:hi] = out[:hi - lo, :d_max] / view.n_addr
             else:
-                e, ff = _ra_ungrouped(ret, rfj, wfj, padm, lt_o,
-                                      reads_o, bits_o)
-                energy[lo:hi] = np.asarray(e)[:hi - lo]
                 frac[lo:hi] = _host_weighted_fracs(
-                    np.asarray(ff)[:hi - lo, :res.n_lt], bits_host,
-                    d_max)
+                    out[:hi - lo, :res.n_lt], bits_host, d_max)
         return energy, frac

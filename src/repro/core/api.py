@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import itertools
 from typing import Iterable, Iterator, Protocol, Sequence, runtime_checkable
 
 import numpy as np
@@ -46,6 +47,7 @@ from repro.core.frontend import (dump_report, stats_from_lifetimes,
 from repro.core.lifetime import (lifetimes_of_trace,
                                  short_lived_fraction as _short_lived)
 from repro.core.trace import Trace
+from repro.runtime import obs
 
 
 # ---------------------------------------------------------------------------
@@ -145,6 +147,9 @@ def resolve_devices(
 # ProfileSession
 # ---------------------------------------------------------------------------
 
+_session_ids = itertools.count(1)
+
+
 class ProfileSession:
     """One profile -> analyze -> compose -> report pipeline run.
 
@@ -152,11 +157,17 @@ class ProfileSession:
     overridable; ``report()`` auto-runs any stage not yet executed with
     its defaults, so ``ProfileSession("systolic").run(workload)`` is the
     whole paper workflow in one line.
+
+    Each stage is a span (``session.profile``, ``session.analyze``,
+    ``session.compose``, ``session.sweep``; see
+    :mod:`repro.runtime.obs`) whose ``session`` attribute is this
+    session's ``id``: the request every span nested in it belongs to.
     """
 
     def __init__(self, backend: Backend | str | None = None, *,
                  devices: Sequence[DeviceModel | str] | None = None,
                  **backend_cfg):
+        self.id = next(_session_ids)
         self.backend = (get_backend(backend) if isinstance(backend, str)
                         else backend)
         self.devices = resolve_devices(devices)
@@ -201,7 +212,8 @@ class ProfileSession:
                                "ProfileSession(backend_name) or use "
                                "from_trace/from_chunks")
         merged = {**self._backend_cfg, **cfg}
-        self._result = self.backend.run(workload, **merged)
+        with obs.span("session.profile", session=self.id):
+            self._result = self.backend.run(workload, **merged)
         self._report = None
         self._acc = None
         self._stats.clear()
@@ -213,58 +225,62 @@ class ProfileSession:
                 devices: Sequence[DeviceModel | str] | None = None,
                 ) -> "ProfileSession":
         """Run the Algorithm-1 frontend over the profiled trace/chunks."""
-        res = self._require_result()
-        mode = mode or res.mode
-        devs = resolve_devices(devices) if devices is not None \
-            else self.devices
-        report = {"mode": mode, "write_allocate": write_allocate,
-                  "subpartitions": {}}
-        self._stats.clear()
-        if res.streaming:
-            acc = self._acc
-            if acc is None:
-                acc = TraceAccumulator(mode=mode,
-                                       write_allocate=write_allocate)
-                for chunk in res.chunks:
-                    acc.update(chunk)
-                acc.finalize()
-                self._acc = acc
-            elif (acc.mode != mode
-                  or acc.write_allocate != write_allocate):
-                # the chunk stream was consumed by the first analyze();
-                # only device-set changes can be recomputed from the fold
-                raise RuntimeError(
-                    "streaming profile results are folded once: "
-                    f"analyzed with mode={acc.mode!r}/"
-                    f"write_allocate={acc.write_allocate}, cannot "
-                    f"re-analyze with mode={mode!r}/"
-                    f"write_allocate={write_allocate}; re-run profile() "
-                    "or feed a fresh iterator to from_chunks()")
-            self._clock_hz = acc.clock_hz
-            for sub in acc.subpartitions:
-                st, raw = acc.stats(sub)
-                self._stats[st.name] = (st, raw)
-                report["subpartitions"][st.name] = \
-                    subpartition_entry(st, devs)
-        else:
-            trace = res.trace
-            self._clock_hz = trace.clock_hz
-            subs = np.unique(np.asarray(trace.subpartition))
-            for sub in subs.tolist():
-                t_sub = trace.select(int(sub))
-                raw = lifetimes_of_trace(t_sub, mode=mode,
-                                         write_allocate=write_allocate)
-                st = stats_from_lifetimes(t_sub, int(sub), raw)
-                self._stats[st.name] = (st, raw)
-                report["subpartitions"][st.name] = \
-                    subpartition_entry(st, devs)
-        if res.kernels:
-            report["kernels"] = [
-                k if isinstance(k, dict) else dataclasses.asdict(k)
-                if dataclasses.is_dataclass(k) else k.__dict__
-                for k in res.kernels]
-        report.update(res.meta)
-        self._report = report
+        with obs.span("session.analyze", session=self.id):
+            res = self._require_result()
+            mode = mode or res.mode
+            devs = resolve_devices(devices) if devices is not None \
+                else self.devices
+            report = {"mode": mode, "write_allocate": write_allocate,
+                      "subpartitions": {}}
+            self._stats.clear()
+            if res.streaming:
+                acc = self._acc
+                if acc is None:
+                    acc = TraceAccumulator(mode=mode,
+                                           write_allocate=write_allocate)
+                    for chunk in res.chunks:
+                        acc.update(chunk)
+                    acc.finalize()
+                    self._acc = acc
+                elif (acc.mode != mode
+                      or acc.write_allocate != write_allocate):
+                    # the chunk stream was consumed by the first analyze();
+                    # only device-set changes can be recomputed from the fold
+                    raise RuntimeError(
+                        "streaming profile results are folded once: "
+                        f"analyzed with mode={acc.mode!r}/"
+                        f"write_allocate={acc.write_allocate}, cannot "
+                        f"re-analyze with mode={mode!r}/"
+                        f"write_allocate={write_allocate}; re-run profile() "
+                        "or feed a fresh iterator to from_chunks()")
+                self._clock_hz = acc.clock_hz
+                for sub in acc.subpartitions:
+                    st, raw = acc.stats(sub)
+                    self._stats[st.name] = (st, raw)
+                    report["subpartitions"][st.name] = \
+                        subpartition_entry(st, devs)
+            else:
+                trace = res.trace
+                self._clock_hz = trace.clock_hz
+                subs = np.unique(np.asarray(trace.subpartition))
+                for sub in subs.tolist():
+                    t_sub = trace.select(int(sub))
+                    with obs.span("lifetime.extract",
+                                  subpartition=trace.sub_name(sub),
+                                  events=t_sub.n_events):
+                        raw = lifetimes_of_trace(
+                            t_sub, mode=mode, write_allocate=write_allocate)
+                    st = stats_from_lifetimes(t_sub, int(sub), raw)
+                    self._stats[st.name] = (st, raw)
+                    report["subpartitions"][st.name] = \
+                        subpartition_entry(st, devs)
+            if res.kernels:
+                report["kernels"] = [
+                    k if isinstance(k, dict) else dataclasses.asdict(k)
+                    if dataclasses.is_dataclass(k) else k.__dict__
+                    for k in res.kernels]
+            report.update(res.meta)
+            self._report = report
         return self
 
     def compose(self, *,
@@ -277,25 +293,27 @@ class ProfileSession:
         ``"refresh-aware"``, ``"bank-quantized[:<base>][@<n_banks>]"`` —
         see :mod:`repro.compose`); ``engine=`` the evaluation backend
         (``"numpy"`` oracle or jitted ``"jax"``)."""
-        if self._report is None:
-            self.analyze()
-        devs = resolve_devices(devices) if devices is not None \
-            else self.devices
-        for name, (st, raw) in self._stats.items():
-            comp = compose_stats(st, raw=raw, devices=devs,
-                                 clock_hz=self._clock_hz, policy=policy,
-                                 engine=engine)
-            self._compositions[name] = comp
-            entry = {
-                "devices": list(comp.devices),
-                "capacity_fractions": comp.capacity_fractions.tolist(),
-                "energy_vs_sram": comp.energy_vs_sram,
-                "area_vs_sram": comp.area_vs_sram,
-                "policy": comp.policy,
-            }
-            if comp.quantization is not None:
-                entry["quantization"] = comp.quantization
-            self._report["subpartitions"][name]["composition"] = entry
+        with obs.span("session.compose", session=self.id,
+                      policy=getattr(policy, "name", policy)):
+            if self._report is None:
+                self.analyze()
+            devs = resolve_devices(devices) if devices is not None \
+                else self.devices
+            for name, (st, raw) in self._stats.items():
+                comp = compose_stats(st, raw=raw, devices=devs,
+                                     clock_hz=self._clock_hz, policy=policy,
+                                     engine=engine)
+                self._compositions[name] = comp
+                entry = {
+                    "devices": list(comp.devices),
+                    "capacity_fractions": comp.capacity_fractions.tolist(),
+                    "energy_vs_sram": comp.energy_vs_sram,
+                    "area_vs_sram": comp.area_vs_sram,
+                    "policy": comp.policy,
+                }
+                if comp.quantization is not None:
+                    entry["quantization"] = comp.quantization
+                self._report["subpartitions"][name]["composition"] = entry
         return self
 
     def sweep(self, grid=None, *, workers: int = 1,
@@ -310,16 +328,17 @@ class ProfileSession:
         With ``attach=True`` the per-subpartition Pareto frontiers are
         also recorded under ``report()["sweep"]``.
         """
-        from repro.sweep import SweepRunner
-        self._require_analyzed()
-        runner = SweepRunner(grid, workers=workers, policy=policy,
-                             engine=engine)
-        result = runner.run_session(self)
-        if attach:
-            self._report["sweep"] = {
-                (sub if geom is None else f"{geom}/{sub}"):
-                frontier.asdict()
-                for (geom, sub), frontier in result.frontiers().items()}
+        with obs.span("session.sweep", session=self.id):
+            from repro.sweep import SweepRunner
+            self._require_analyzed()
+            runner = SweepRunner(grid, workers=workers, policy=policy,
+                                 engine=engine)
+            result = runner.run_session(self)
+            if attach:
+                self._report["sweep"] = {
+                    (sub if geom is None else f"{geom}/{sub}"):
+                    frontier.asdict()
+                    for (geom, sub), frontier in result.frontiers().items()}
         return result
 
     def report(self, path: str | None = None) -> dict:

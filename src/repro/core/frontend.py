@@ -21,6 +21,7 @@ import numpy as np
 from repro.core.devices import DEFAULT_DEVICES, DeviceModel
 from repro.core.lifetime import LifetimeStats, lifetimes_of_trace
 from repro.core.trace import Trace
+from repro.runtime import obs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,18 +80,24 @@ def stats_from_lifetimes(
     """Build SubpartitionStats from a single-subpartition trace and its
     already-extracted lifetimes (shared by compute_stats and the
     ProfileSession pipeline, which reuses the extraction for compose())."""
-    n_reads, n_writes = t.counts()
-    addrs = np.asarray(t.addr)
-    n_unique = int(len(np.unique(addrs))) if len(addrs) else 0
-    dur = max(t.duration_s, 1e-30)
+    name = t.sub_name(sub)
+    with obs.span("frontend.stats", subpartition=name):
+        n_reads, n_writes = t.counts()
+        addrs = np.asarray(t.addr)
+        n_unique = int(len(np.unique(addrs))) if len(addrs) else 0
+        dur = max(t.duration_s, 1e-30)
 
-    valid = np.asarray(stats.valid)
-    lt_s = np.asarray(stats.lifetime_cycles)[valid] / t.clock_hz
-    n_rd = np.asarray(stats.n_reads)[valid]
-    orphan = np.asarray(stats.orphan)[valid]
+        valid, lt_cyc, n_rd, orphan = (np.asarray(x) for x in (
+            stats.valid, stats.lifetime_cycles, stats.n_reads,
+            stats.orphan))
+        obs.count("d2h_bytes", valid.nbytes + lt_cyc.nbytes + n_rd.nbytes
+                  + orphan.nbytes)
+        lt_s = lt_cyc[valid] / t.clock_hz
+        n_rd = n_rd[valid]
+        orphan = orphan[valid]
 
     return SubpartitionStats(
-        name=t.names[sub] if sub < len(t.names) else f"sub{sub}",
+        name=name,
         n_reads=n_reads,
         n_writes=n_writes,
         n_unique_addrs=n_unique,

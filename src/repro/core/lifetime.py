@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.compat import enable_x64
 from repro.core.trace import Trace
+from repro.runtime import obs
 
 # "no read yet" sentinel: below any real int64 cycle stamp, with headroom
 # so segment arithmetic cannot overflow (repro.core.accumulate mirrors it).
@@ -90,12 +91,13 @@ def extract_lifetimes(
     if mode not in ("scratchpad", "cache"):
         raise ValueError(f"unknown mode {mode!r}")
     with enable_x64():
-        return _extract_lifetimes(
-            jnp.asarray(np.asarray(time_cycles), jnp.int64),
-            jnp.asarray(np.asarray(addr), jnp.int64),
-            jnp.asarray(np.asarray(is_write), bool),
-            jnp.asarray(np.asarray(hit), bool),
-            mode=mode, write_allocate=write_allocate)
+        args = (jnp.asarray(np.asarray(time_cycles), jnp.int64),
+                jnp.asarray(np.asarray(addr), jnp.int64),
+                jnp.asarray(np.asarray(is_write), bool),
+                jnp.asarray(np.asarray(hit), bool))
+        obs.count("h2d_bytes", sum(a.nbytes for a in args))
+        return _extract_lifetimes(*args, mode=mode,
+                                  write_allocate=write_allocate)
 
 
 @partial(jax.jit, static_argnames=("mode", "write_allocate"))

@@ -74,6 +74,10 @@ class Trace:
             names=self.names,
         )
 
+    def sub_name(self, sub: int) -> str:
+        """Name of subpartition index ``sub``."""
+        return self.names[sub] if sub < len(self.names) else f"sub{sub}"
+
     def counts(self):
         w = np.asarray(self.is_write)
         return int((~w).sum()), int(w.sum())  # (reads, writes)

@@ -27,6 +27,7 @@ way a 4-thread sweep is bit-for-bit identical to the serial one
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 from concurrent.futures import ThreadPoolExecutor
 from typing import Mapping, Sequence
@@ -240,6 +241,10 @@ class SweepRunner:
         if self.workers == 1 or len(items) <= 1:
             chunks = [fn(it) for it in items]
         else:
+            # each item runs in a copy of this thread's context, so the
+            # spans it opens nest under the caller's (repro.runtime.obs)
+            ctxs = [contextvars.copy_context() for _ in items]
             with ThreadPoolExecutor(max_workers=self.workers) as pool:
-                chunks = list(pool.map(fn, items))
+                chunks = list(pool.map(
+                    lambda ctx, it: ctx.run(fn, it), ctxs, items))
         return [p for chunk in chunks for p in chunk]
