@@ -286,7 +286,7 @@ def _rf_fused(ret, read_fj, write_fj, pad, fallback,
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
 def _ra_grouped(ret, read_fj, write_fj, pad,
-                lt, reads, bits, seg, n_addr, *, n_seg):
+                lt, reads, bits, seg, n_addr, n_real, *, n_seg):
     """Refresh-aware, one fixed-width candidate slab against the
     resident addr-sorted arrays.  Same decomposition as the PR-9 kernel
     (separable base terms + one refresh segment sum) so argmin ties
@@ -300,7 +300,12 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
     a time over 1-D data: on a TPU an array whose last axis is the
     device (or candidate) axis is padded to 128 lanes, which at a few
     million lifetimes does not fit in HBM.  Candidates run one after
-    another (``lax.map``) for the same reason."""
+    another (``lax.map``) for the same reason.
+
+    Only the first ``n_real`` rows (a traced scalar, so the slab's
+    shape and its executable stay the same) are candidates: each row
+    takes a ``lax.cond`` on its index, and padded rows take the branch
+    that returns zeros without computing anything."""
     rb = reads * bits
     ss = functools.partial(jax.ops.segment_sum, segment_ids=seg,
                            num_segments=n_seg, indices_are_sorted=True)
@@ -329,7 +334,16 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
                   & amask[None, :]).sum(axis=1)
         return energy, counts.astype(jnp.float64)
 
-    return jax.lax.map(one, (ret, read_fj, write_fj, pad))
+    def skip(_):
+        return jnp.zeros((), jnp.float64), jnp.zeros(ret.shape[1],
+                                                     jnp.float64)
+
+    def row(args):
+        i, cand = args
+        return jax.lax.cond(i < n_real, one, skip, cand)
+
+    rows = jnp.arange(ret.shape[0])
+    return jax.lax.map(row, (rows, (ret, read_fj, write_fj, pad)))
 
 
 @jax.jit
@@ -459,8 +473,11 @@ def run_batch(pol, batch, view):
                 # out: pick counts [slab, D] when grouped, else each
                 # lifetime's pick [slab, L]
                 if grouped:
+                    # padded rows the kernel skips
+                    obs.count("slab_rows_skipped", slab - (hi - lo))
                     e, out = _ra_grouped(ret, rfj, wfj, padm, lt_a,
                                          reads_a, bits_a, seg, n_addr,
+                                         _put(np.int64(hi - lo)),
                                          n_seg=res.A_pad)
                 else:
                     e, out = _ra_ungrouped(ret, rfj, wfj, padm, lt_o,

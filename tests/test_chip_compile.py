@@ -90,8 +90,11 @@ def test_refresh_aware_executor_compiles_at_floor_buckets(one_chip):
         compiled = executor._ra_grouped.lower(
             S((C, D), f64), S((C, D), f64), S((C, D), f64),
             S((C, D), bool), S((L,), f64), S((L,), f64), S((L,), f64),
-            S((L,), jnp.int32), S((), jnp.int64), n_seg=A).compile()
+            S((L,), jnp.int32), S((), jnp.int64), S((), jnp.int64),
+            n_seg=A).compile()
     assert compiled.memory_analysis() is not None
+    # padded rows skip the row's work: a branch, not a select of both
+    assert "conditional(" in compiled.as_text()
 
 
 def test_lifetime_scan_kernel_compiles_for_tpu(one_chip):

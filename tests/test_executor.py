@@ -7,7 +7,12 @@ The locked contracts:
     device/candidate sizes straddling the pow2 bucket boundaries, so
     masked padding provably never leaks into results;
   - a second workload whose padded shapes land in the same buckets
-    triggers zero new jit compiles (``compile_stats`` telemetry);
+    triggers zero new jit compiles (``compile_stats`` telemetry), and
+    so does a refresh-aware batch whose real rows fill less or more of
+    the slab;
+  - the refresh-aware grouped slab computes only its real rows: padded
+    rows come back as the skip branch's zeros, counted as
+    ``slab_rows_skipped``;
   - the device-resident trace view is built once per (stats, raw)
     pair and reused across evaluate() calls;
   - a 4-thread ``SweepRunner`` on the jax engine is bit-for-bit equal
@@ -22,10 +27,13 @@ import jax
 import numpy as np
 import pytest
 
+from repro.compat import enable_x64
 from repro.compose import compile_stats
 from repro.compose import engine as compose_engine
+from repro.compose import executor
 from repro.compose.engine import evaluate
 from repro.core.frontend import SubpartitionStats
+from repro.runtime import obs
 from repro.sweep import (SRAM_ONLY_ID, DeviceGrid, FamilyGrid, SweepRunner,
                          pareto_frontier)
 
@@ -96,11 +104,86 @@ def test_fused_batch_matches_numpy_oracle_all_paths():
             _assert_matches_oracle(cands, st, raw=use_raw, policy=policy)
 
 
+def _ra_grid_cands():
+    """18 two-device candidates (the grid's SRAM-only anchor left out)."""
+    grid = DeviceGrid(mixes=(0.0, 0.5, 1.0),
+                      retention_scales=(0.5, 1.0, 2.0),
+                      energy_scales=(0.7, 1.4), per_mix=True)
+    return [c.devices for c in grid.candidates()][1:]
+
+
+# one partial slab, one full, one full plus a row, two full plus a row
+@pytest.mark.parametrize("n_cands", [1, 3, 8, 9, 17])
+@pytest.mark.parametrize("policy",
+                         ["refresh-aware", "bank-quantized:refresh-aware@8"])
+def test_refresh_aware_slab_rows_match_numpy_oracle(monkeypatch, policy,
+                                                    n_cands):
+    # a budget that holds no more than the floor: 8-row slabs, as at the
+    # chip's trace sizes, so a batch's last slab is partly padding
+    monkeypatch.setattr(executor, "_SLAB_BYTES", 1)
+    st, raw = _synth(n=3000, n_addr=300, seed=n_cands)
+    _assert_matches_oracle(_ra_grid_cands()[:n_cands], st, raw=raw,
+                           policy=policy)
+
+
+def _ra_slab_inputs(rng, rows=8, d=2, n=2048, n_seg=256):
+    ret = rng.uniform(1e2, 1e4, (rows, d))
+    ret[:, 0] = np.inf                                  # SRAM
+    read_fj = rng.uniform(1.0, 5.0, (rows, d))
+    write_fj = rng.uniform(1.0, 5.0, (rows, d))
+    pad = np.zeros((rows, d), bool)
+    lt = rng.uniform(1.0, 1e4, n)
+    reads = rng.poisson(3.0, n).astype(np.float64)
+    bits = np.full(n, 256.0)
+    seg = np.sort(rng.randint(0, n_seg - 6, n)).astype(np.int32)
+    return ret, read_fj, write_fj, pad, lt, reads, bits, seg
+
+
+def test_ra_grouped_skips_rows_past_the_real_count():
+    args = _ra_slab_inputs(np.random.RandomState(5))
+    n_seg = 256
+    with enable_x64():
+        def call(n_real):
+            e, cnt = executor._ra_grouped(
+                *args, np.int64(n_seg - 6), np.int64(n_real), n_seg=n_seg)
+            return np.asarray(e), np.asarray(cnt)
+        e1, c1 = call(1)
+        e8, c8 = call(8)
+    # computed, every row has energy and picks; skipped, it is all zeros
+    assert (e8 > 0).all() and (c8.sum(axis=1) == n_seg - 6).all()
+    assert (e1[1:] == 0).all() and (c1[1:] == 0).all()
+    assert e1[0] == e8[0]
+    assert np.array_equal(c1[0], c8[0])
+
+
+def test_slab_rows_skipped_counts_the_padded_rows(monkeypatch):
+    monkeypatch.setattr(executor, "_SLAB_BYTES", 1)     # 8-row slabs
+    st, raw = _synth(n=2500, n_addr=200, seed=8)
+    with obs.span("mark"):
+        pass
+    mark = obs.snapshot()["spans"][-1]["id"]
+    for policy in ("refresh-free", "refresh-aware"):
+        evaluate(_ra_grid_cands()[:17], st, raw=raw, clock_hz=CLOCK,
+                 policy=policy, engine="jax")
+    slabs = [s for s in obs.snapshot()["spans"]
+             if s["id"] > mark and s["name"] == "executor.slab"]
+    assert [s["attrs"]["kernel"] for s in slabs] == \
+        ["rf_fused"] + ["ra_grouped"] * 3
+    assert "slab_rows_skipped" not in slabs[0]["counts"]
+    ra = [s["counts"] for s in slabs[1:]]
+    for c in ra:
+        assert c["slab_rows_skipped"] == c["slab_rows"] - c["slab_real_rows"]
+    assert [c["slab_rows_skipped"] for c in ra] == [0, 0, 7]
+
+
 # ---------------------------------------------------------------------------
 # shape buckets: second workload in the same bucket -> zero new compiles
 # ---------------------------------------------------------------------------
 
-def test_same_bucket_workload_triggers_zero_new_compiles():
+def test_same_bucket_workload_triggers_zero_new_compiles(monkeypatch):
+    # the refresh-aware slab held at its 8-row floor, as the broadcast
+    # budget holds it at the chip's trace sizes
+    monkeypatch.setattr(executor, "_SLAB_BYTES", 1)
     # workload A: n=3000 -> L bucket 4096, n_addr=300 -> A bucket 512
     st_a, raw_a = _synth(n=3000, n_addr=300, seed=0)
     grid_a = DeviceGrid(mixes=(0.0, 0.5, 1.0),
@@ -125,6 +208,14 @@ def test_same_bucket_workload_triggers_zero_new_compiles():
         for use_raw in (raw_b, None):
             evaluate(cands_b, st_b, raw=use_raw, clock_hz=CLOCK,
                      policy=policy, engine="jax")
+    assert compile_stats()["jit_entries"] == entries
+
+    # one real row of an 8-row slab, then 16 rows in two full slabs:
+    # the real-row count is traced, so both run workload A's executable
+    widest = [c for c in cands_a if len(c) == 2]
+    for cands in (widest[:1], (widest * 3)[:16]):
+        evaluate(cands, st_b, raw=raw_b, clock_hz=CLOCK,
+                 policy="refresh-aware", engine="jax")
     assert compile_stats()["jit_entries"] == entries
 
 
