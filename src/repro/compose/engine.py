@@ -147,8 +147,10 @@ class TraceView:
     relative.  Address-sorted side (refresh-aware segment reductions):
     the lifetime arrays gathered through ``groups.order`` with dense
     segment ids, and each address's max lifetime value-sorted for the
-    capacity searchsorted.  Address fields are ``None`` when built with
-    ``raw=None``.
+    capacity searchsorted.  ``order`` gives each value-sorted lifetime's
+    index in the original order, ``addr_pos`` each original lifetime's
+    position on the address-sorted side.  Address fields are ``None``
+    when built with ``raw=None``.
     """
     n_lt: int
     n_addr: int
@@ -160,6 +162,8 @@ class TraceView:
     reads_addr: np.ndarray | None
     bits_addr: np.ndarray | None
     seg: np.ndarray | None
+    order: np.ndarray | None = None
+    addr_pos: np.ndarray | None = None
 
 
 def _build_trace_view(stats: SubpartitionStats, raw,
@@ -178,6 +182,7 @@ def _build_trace_view(stats: SubpartitionStats, raw,
         return p.astype(np.float64)
 
     maxlt_sorted = lt_addr = reads_addr = bits_addr = seg = None
+    addr_pos = None
     n_addr = 0
     if raw is not None:
         groups = address_groups(raw, clock_hz)
@@ -190,11 +195,14 @@ def _build_trace_view(stats: SubpartitionStats, raw,
         seg = np.zeros(n_lt, np.int32)
         seg[np.asarray(groups.starts)[1:]] = 1  # starts[0] == 0: segment 0
         seg = np.cumsum(seg, dtype=np.int32)
+        addr_pos = np.empty(n_lt, np.int64)
+        addr_pos[g_order] = np.arange(n_lt)
     return TraceView(
         n_lt=n_lt, n_addr=n_addr, lt_sorted=lt[order],
         prefix_bits=prefix(bits), prefix_read_bits=prefix(reads * bits),
         maxlt_sorted=maxlt_sorted, lt_addr=lt_addr,
-        reads_addr=reads_addr, bits_addr=bits_addr, seg=seg)
+        reads_addr=reads_addr, bits_addr=bits_addr, seg=seg, order=order,
+        addr_pos=addr_pos)
 
 
 # Memo for sorted_trace_view: (id(stats), id(raw)) -> (weakref(stats),

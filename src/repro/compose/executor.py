@@ -92,6 +92,18 @@ _C_MIN = 8          # candidates (refresh-free batch / refresh-aware slab)
 # broadcast itemsize.
 _SLAB_BYTES = 256 * 1024 * 1024
 
+# A refresh count is ceil(T / t_ret) - 1.  Where T / t_ret lies within a
+# few ulps of an integer - a lifetime that is an exact multiple of a
+# retention, as one of k microseconds on a 1-us gain cell - a division
+# that is not correctly rounded (a TPU emulates float64) can round the
+# quotient across the integer, and ceil bills one refresh more or less
+# than the float64 oracle.  The host finds those lifetimes (quotients
+# within _NEAR_INT of an integer, far wider than the device's error)
+# and hands the kernels the oracle's count for each, in fixed-width
+# lists of at least _FIX_MIN entries per candidate device.
+_NEAR_INT = 1e-11
+_FIX_MIN = 64
+
 
 def _next_pow2(n: int, lo: int) -> int:
     p = lo
@@ -240,6 +252,59 @@ def _residence_for(view) -> _TraceResidence:
     return res
 
 
+def _near_integer_ranks(lt_sorted: np.ndarray, r: float) -> np.ndarray:
+    """Value-sorted ranks of the lifetimes whose quotient by retention
+    ``r`` lies within ``_NEAR_INT`` (relative) of an integer >= 1."""
+    if not (np.isfinite(r) and r > 0) or lt_sorted.size == 0:
+        return np.zeros(0, np.int64)
+    k_max = int(lt_sorted[-1] / r * (1.0 + _NEAR_INT))
+    if k_max > lt_sorted.size:          # tiny retention: test each lifetime
+        q = lt_sorted / r
+        k = np.round(q)
+        return np.flatnonzero((k >= 1) & (np.abs(q - k) <= _NEAR_INT * q))
+    kr = np.arange(1, k_max + 1) * r
+    lo = np.searchsorted(lt_sorted, kr * (1.0 - _NEAR_INT))
+    n = np.searchsorted(lt_sorted, kr * (1.0 + _NEAR_INT), side="right") - lo
+    return np.repeat(lo - np.cumsum(n) + n, n) + np.arange(int(n.sum()))
+
+
+def _refresh_fixes(view, ret: np.ndarray, rows: int, d_pad: int,
+                   grouped: bool):
+    """``(idx, cnt)``, each ``[rows, d_pad, N]``: for every candidate
+    device of ``ret`` (``[C, D]``, C <= rows), the lifetimes whose
+    refresh count a device division may round wrongly
+    (:func:`_near_integer_ranks`), as positions in the kernel's lifetime
+    order (address-sorted when ``grouped``, else original), and their
+    oracle counts ``max(ceil(T / t_ret) - 1, 0)`` in float64.  Unused
+    entries point past the lifetimes (dropped by the kernel's scatter).
+    ``N`` is a power of two, at least ``_FIX_MIN``."""
+    found = {}
+    for (c, d), r in np.ndenumerate(ret):
+        ranks = _near_integer_ranks(view.lt_sorted, r)
+        if ranks.size:
+            found[c, d] = ranks
+    n = _next_pow2(max((len(v) for v in found.values()), default=0),
+                   _FIX_MIN)
+    l_pad = _next_pow2(view.n_lt, _L_MIN)
+    idx = np.full((rows, d_pad, n), l_pad, np.int64)
+    cnt = np.zeros((rows, d_pad, n))
+    for (c, d), ranks in found.items():
+        pos = view.order[ranks]
+        idx[c, d, :len(ranks)] = view.addr_pos[pos] if grouped else pos
+        cnt[c, d, :len(ranks)] = np.maximum(
+            np.ceil(view.lt_sorted[ranks] / ret[c, d]) - 1.0, 0.0)
+    return idx, cnt
+
+
+def _refresh_counts(lt, ret_r, fix_idx, fix_cnt):
+    """``[D, L]`` refresh counts ``max(ceil(lt / ret) - 1, 0)`` of every
+    lifetime on every device, with the host's exact counts at
+    ``fix_idx`` (:func:`_refresh_fixes`)."""
+    n = jnp.maximum(jnp.ceil(lt[None, :] / ret_r[:, None]) - 1.0, 0.0)
+    dev = jnp.arange(ret_r.shape[0])[:, None]
+    return n.at[dev, fix_idx].set(fix_cnt, mode="drop")
+
+
 # ---------------------------------------------------------------------------
 # fused kernels
 # ---------------------------------------------------------------------------
@@ -286,7 +351,8 @@ def _rf_fused(ret, read_fj, write_fj, pad, fallback,
 
 @functools.partial(jax.jit, static_argnames=("n_seg",))
 def _ra_grouped(ret, read_fj, write_fj, pad,
-                lt, reads, bits, seg, n_addr, n_real, *, n_seg):
+                lt, reads, bits, seg, n_addr, n_real, fix_idx, fix_cnt, *,
+                n_seg):
     """Refresh-aware, one fixed-width candidate slab against the
     resident addr-sorted arrays.  Same decomposition as the PR-9 kernel
     (separable base terms + one refresh segment sum) so argmin ties
@@ -305,7 +371,9 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
     Only the first ``n_real`` rows (a traced scalar, so the slab's
     shape and its executable stay the same) are candidates: each row
     takes a ``lax.cond`` on its index, and padded rows take the branch
-    that returns zeros without computing anything."""
+    that returns zeros without computing anything.  Refresh counts
+    near an integer quotient come from ``fix_idx``/``fix_cnt``
+    (:func:`_refresh_counts`)."""
     rb = reads * bits
     ss = functools.partial(jax.ops.segment_sum, segment_ids=seg,
                            num_segments=n_seg, indices_are_sorted=True)
@@ -315,10 +383,9 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
     dev_ids = jnp.arange(ret.shape[1])
 
     def one(args):
-        ret_r, rf_r, wf_r, pad_r = args
-        refresh_e = (jnp.maximum(
-            jnp.ceil(lt[None, :] / ret_r[:, None]) - 1.0, 0.0)
-            * bits[None, :])                                # [D, L]
+        ret_r, rf_r, wf_r, pad_r, fi_r, fc_r = args
+        refresh_e = (_refresh_counts(lt, ret_r, fi_r, fc_r)
+                     * bits[None, :])                       # [D, L]
         rw = rf_r + wf_r
         e = (wf_r[:, None] * bits[None, :]
              + rf_r[:, None] * rb[None, :]
@@ -343,16 +410,17 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
         return jax.lax.cond(i < n_real, one, skip, cand)
 
     rows = jnp.arange(ret.shape[0])
-    return jax.lax.map(row, (rows, (ret, read_fj, write_fj, pad)))
+    return jax.lax.map(row, (rows, (ret, read_fj, write_fj, pad, fix_idx,
+                                    fix_cnt)))
 
 
 @jax.jit
-def _ra_ungrouped(ret, read_fj, write_fj, pad, lt, reads, bits):
+def _ra_ungrouped(ret, read_fj, write_fj, pad, lt, reads, bits, fix_idx,
+                  fix_cnt):
     """Refresh-aware without address groups: per-lifetime argmin picks
     (original element order) for the host's exact weighted fractions."""
-    def one(ret_r, rf_r, wf_r, pad_r):
-        refresh = jnp.maximum(
-            jnp.ceil(lt[None, :] / ret_r[:, None]) - 1.0, 0.0)
+    def one(ret_r, rf_r, wf_r, pad_r, fi_r, fc_r):
+        refresh = _refresh_counts(lt, ret_r, fi_r, fc_r)
         rw = rf_r[:, None] + wf_r[:, None]
         e = bits[None, :] * (wf_r[:, None]
                              + reads[None, :] * rf_r[:, None]
@@ -361,7 +429,7 @@ def _ra_ungrouped(ret, read_fj, write_fj, pad, lt, reads, bits):
         ff = jnp.argmin(e, axis=0)
         e_sel = jnp.take_along_axis(e, ff[None, :], axis=0)[0]
         return e_sel.sum() * 1e-15, ff
-    return jax.vmap(one)(ret, read_fj, write_fj, pad)
+    return jax.vmap(one)(ret, read_fj, write_fj, pad, fix_idx, fix_cnt)
 
 
 # ---------------------------------------------------------------------------
@@ -470,6 +538,8 @@ def run_batch(pol, batch, view):
             with _slab(kernel, slab, hi - lo):
                 ret, rfj, wfj, padm = _slab_inputs(batch, lo, hi, slab,
                                                    d_pad)
+                fix_idx, fix_cnt = map(_put, _refresh_fixes(
+                    view, batch.ret_s[lo:hi], slab, d_pad, grouped))
                 # out: pick counts [slab, D] when grouped, else each
                 # lifetime's pick [slab, L]
                 if grouped:
@@ -477,11 +547,12 @@ def run_batch(pol, batch, view):
                     obs.count("slab_rows_skipped", slab - (hi - lo))
                     e, out = _ra_grouped(ret, rfj, wfj, padm, lt_a,
                                          reads_a, bits_a, seg, n_addr,
-                                         _put(np.int64(hi - lo)),
-                                         n_seg=res.A_pad)
+                                         _put(np.int64(hi - lo)), fix_idx,
+                                         fix_cnt, n_seg=res.A_pad)
                 else:
                     e, out = _ra_ungrouped(ret, rfj, wfj, padm, lt_o,
-                                           reads_o, bits_o)
+                                           reads_o, bits_o, fix_idx,
+                                           fix_cnt)
                 e, out = _pull(e), _pull(out)
             energy[lo:hi] = e[:hi - lo]
             if grouped:

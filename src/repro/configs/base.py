@@ -48,6 +48,11 @@ class ArchConfig:
     moe_d_ff: Optional[int] = None       # routed-expert hidden size
     moe_every: int = 1                   # MoE layer cadence (1 = all)
     moe_first_dense: int = 0             # leading dense layers (deepseek-moe)
+    # --- multi-head latent attention (deepseek-v2; no query LoRA) ---
+    kv_lora_rank: int = 0                # latent width; 0 = no MLA
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -88,10 +93,19 @@ class ArchConfig:
         return self.family in ("ssm", "hybrid")
 
     def param_count(self) -> int:
-        """Approximate total parameters (embedding + blocks)."""
+        """Approximate total parameters (embedding + blocks + final
+        norm)."""
         D, F, V = self.d_model, self.d_ff, self.vocab
         hd, H, KV = self.hd, self.n_heads, self.kv_heads
-        attn = D * (H * hd) + 2 * D * (KV * hd) + (H * hd) * D
+        if self.kv_lora_rank:
+            dc, dr = self.kv_lora_rank, self.qk_rope_head_dim
+            dn, dv = self.qk_nope_head_dim, self.v_head_dim
+            # q_proj, kv_a (latent + shared rope key), latent norm,
+            # kv_b (per-head no-rope key and value), o_proj
+            attn = (D * H * (dn + dr) + D * (dc + dr) + dc
+                    + dc * H * (dn + dv) + H * dv * D)
+        else:
+            attn = D * (H * hd) + 2 * D * (KV * hd) + (H * hd) * D
         dense_mlp = 3 * D * F
         p = 0
         if self.family == "ssm":
@@ -123,7 +137,7 @@ class ArchConfig:
                 # encoder blocks + decoder cross-attention
                 p += self.enc_layers * (attn + dense_mlp + 2 * D)
                 p += self.n_layers * (attn + D)
-        p += V * D * (1 if self.tie_embeddings else 2)
+        p += V * D * (1 if self.tie_embeddings else 2) + D
         return p
 
     def active_param_count(self) -> int:

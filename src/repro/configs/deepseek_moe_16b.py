@@ -5,7 +5,7 @@ from repro.configs.base import ArchConfig
 CONFIG = ArchConfig(
     name="deepseek-moe-16b", family="moe",
     n_layers=28, d_model=2048, n_heads=16, kv_heads=16,
-    d_ff=1408, vocab=102400,
+    d_ff=10944, vocab=102400,                # the leading dense layer
     moe_experts=64, moe_topk=6, moe_shared_experts=2, moe_d_ff=1408,
     moe_first_dense=1,
 )

@@ -1,11 +1,13 @@
 """Built-in workload suites (paper Table 5 analogues).
 
-Three suites, all registered with :func:`repro.workloads.register_workload`:
+The suites, all registered with :func:`repro.workloads.register_workload`:
 
   ``archs``      the framework's ten assigned architecture configs
                  (``repro.configs``), lowered to decoder-block GEMM
                  stacks / op streams / jaxpr traces — these were the
                  ad-hoc builders hand-wired inside ``launch/profile.py``
+  ``archs-trace``  configs with an op-stream lowering and no JAX model
+                 (``deepseek-v2-lite``: latent attention)
   ``mlperf``     the MLPerf-Inference-style model set the paper's GPU
                  tables sweep (previously ``benchmarks/workloads.py``)
   ``polybench``  PolyBench kernels: 2mm/3mm GEMM chains + 2D/3D stencils
@@ -49,14 +51,27 @@ def transformer_gemms(cfg, seq: int, n_layers: int = 2):
 
 
 def transformer_program(cfg, seq: int, n_layers: int = 2):
-    """Op-stream program for the cache-hierarchy ("gpu") backend."""
+    """Op-stream program for the cache-hierarchy ("gpu") backend: a
+    sparse-expert config through ``moe_decoder_ops`` (its default
+    seeded, skewed routing), a dense one through ``transformer_ops``."""
     def program(sb):
-        from repro.backends.opstream import transformer_ops
-        transformer_ops(sb, cfg.d_model, max(cfg.n_heads, 1),
-                        max(cfg.kv_heads, 1), cfg.d_ff or 4 * cfg.d_model,
-                        seq, n_layers=n_layers,
-                        moe_experts=cfg.moe_experts,
-                        moe_topk=cfg.moe_topk)
+        from repro.backends.opstream import moe_decoder_ops, transformer_ops
+        d_ff = cfg.d_ff or 4 * cfg.d_model
+        if not cfg.moe_experts:
+            transformer_ops(sb, cfg.d_model, max(cfg.n_heads, 1),
+                            max(cfg.kv_heads, 1), d_ff, seq,
+                            n_layers=n_layers)
+            return
+        moe_decoder_ops(
+            sb, cfg.d_model, cfg.n_heads, seq, n_layers, d_ff=d_ff,
+            moe_experts=cfg.moe_experts, moe_topk=cfg.moe_topk,
+            moe_d_ff=cfg.moe_d_ff,
+            moe_shared_experts=cfg.moe_shared_experts,
+            moe_first_dense=cfg.moe_first_dense, kv_heads=cfg.kv_heads,
+            kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim)
     return program
 
 
@@ -81,12 +96,16 @@ _ARCH_BACKENDS = ("systolic", "cachesim", "opstream", "tpu_graph")
 
 
 def _register_arch(arch: str) -> None:
+    from repro.configs.base import get_config
+
     @register_workload(
         arch, suite="archs",
         description=f"decoder-block stack of the {arch} config "
                     "(full config for trace backends, smoke for tpu)",
         params={"seq": 128, "n_layers": 2, "tpu_smoke": True},
-        backends=_ARCH_BACKENDS)
+        backends=_ARCH_BACKENDS,
+        # 2: sparse experts lowered by moe_decoder_ops
+        version=2 if get_config(arch).moe_experts else 1)
     def _build(params, backend, _arch=arch):
         from repro.configs.base import get_config
         seq, n_layers = params["seq"], params["n_layers"]
@@ -112,6 +131,22 @@ def _register_archs() -> None:
 _register_archs()
 
 
+@register_workload(
+    "deepseek-v2-lite", suite="archs-trace",
+    description="DeepSeek-V2-Lite block stack (latent attention, 64 "
+                "routed + 2 shared experts, first layer dense) at "
+                "published widths, routed by a seeded skewed router; "
+                "trace backends only, as no JAX model has latent "
+                "attention (so it is not in ARCH_IDS)",
+    params={"seq": 128, "n_layers": 2},
+    backends=("cachesim", "opstream"))
+def _deepseek_v2_lite(params, backend):
+    from repro.configs.base import get_config
+    return (transformer_program(get_config("deepseek_v2_lite"),
+                                params["seq"], params["n_layers"]),
+            {"sample": 8})
+
+
 # ---------------------------------------------------------------------------
 # "mlperf" suite (formerly benchmarks/workloads.py)
 # ---------------------------------------------------------------------------
@@ -127,7 +162,9 @@ def _register_transformer(name, *, d_model, n_heads, kv_heads, d_ff, seq,
                 "kv_heads": kv_heads, "d_ff": d_ff, "seq": seq,
                 "n_layers": n_layers, "moe_experts": moe_experts,
                 "moe_topk": moe_topk, "sample": sample},
-        backends=("systolic", "cachesim", "opstream"))
+        backends=("systolic", "cachesim", "opstream"),
+        # 2: sparse experts lowered by moe_decoder_ops
+        version=2 if moe_experts else 1)
     def _build(params, backend):
         p = dict(params)
         sample = p.pop("sample")
@@ -141,11 +178,16 @@ def _register_transformer(name, *, d_model, n_heads, kv_heads, d_ff, seq,
             return transformer_gemms(dims, p["seq"], p["n_layers"]), {}
 
         def program(sb):
-            from repro.backends.opstream import transformer_ops
-            transformer_ops(sb, p["d_model"], p["n_heads"], p["kv_heads"],
-                            p["d_ff"], p["seq"], n_layers=p["n_layers"],
+            from repro.backends.opstream import moe_decoder_ops, transformer_ops
+            if not p["moe_experts"]:
+                transformer_ops(sb, p["d_model"], p["n_heads"],
+                                p["kv_heads"], p["d_ff"], p["seq"],
+                                n_layers=p["n_layers"])
+                return
+            moe_decoder_ops(sb, p["d_model"], p["n_heads"], p["seq"],
+                            p["n_layers"], d_ff=p["d_ff"],
                             moe_experts=p["moe_experts"],
-                            moe_topk=p["moe_topk"])
+                            moe_topk=p["moe_topk"], kv_heads=p["kv_heads"])
         return program, {"sample": sample}
 
 

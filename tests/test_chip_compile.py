@@ -91,7 +91,8 @@ def test_refresh_aware_executor_compiles_at_floor_buckets(one_chip):
             S((C, D), f64), S((C, D), f64), S((C, D), f64),
             S((C, D), bool), S((L,), f64), S((L,), f64), S((L,), f64),
             S((L,), jnp.int32), S((), jnp.int64), S((), jnp.int64),
-            n_seg=A).compile()
+            S((C, D, executor._FIX_MIN), jnp.int64),
+            S((C, D, executor._FIX_MIN), f64), n_seg=A).compile()
     assert compiled.memory_analysis() is not None
     # padded rows skip the row's work: a branch, not a select of both
     assert "conditional(" in compiled.as_text()

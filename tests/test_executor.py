@@ -139,13 +139,83 @@ def _ra_slab_inputs(rng, rows=8, d=2, n=2048, n_seg=256):
     return ret, read_fj, write_fj, pad, lt, reads, bits, seg
 
 
+def _multiples_of_a_microsecond(seed=3):
+    """A trace whose every fourth lifetime lasts a whole number of
+    microseconds: on the paper's 1-us Si gain cell its quotient T /
+    t_ret is an integer, or a float64 ulp off one."""
+    st, raw = _synth(n=3000, n_addr=300, seed=seed)
+    lt = raw.lifetime_cycles.copy()
+    lt[::4] = 1000 * np.random.RandomState(seed).randint(1, 40, lt[::4].size)
+    return (dataclasses.replace(st, lifetimes_s=lt / CLOCK),
+            dataclasses.replace(raw, lifetime_cycles=lt))
+
+
+def _inexact_device_division(monkeypatch, rel):
+    """The kernels' refresh counts from a division off by ``rel``
+    (relative), as an emulated float64 division on a TPU may be; the
+    host's fix lists still apply."""
+    import jax.numpy as jnp
+
+    def counts(lt, ret_r, fix_idx, fix_cnt):
+        n = jnp.maximum(jnp.ceil(lt[None, :] / ret_r[:, None] * (1.0 + rel))
+                        - 1.0, 0.0)
+        dev = jnp.arange(ret_r.shape[0])[:, None]
+        return n.at[dev, fix_idx].set(fix_cnt, mode="drop")
+    monkeypatch.setattr(executor, "_refresh_counts", counts)
+    jax.clear_caches()          # retrace the kernels with it
+
+
+@pytest.mark.parametrize("use_raw", [True, False])
+@pytest.mark.parametrize("policy",
+                         ["refresh-aware", "bank-quantized:refresh-aware@8"])
+def test_refresh_counts_exact_under_an_inexact_device_division(
+        monkeypatch, use_raw, policy):
+    from repro.core.devices import DEFAULT_DEVICES
+    st, raw = _multiples_of_a_microsecond()
+    raw = raw if use_raw else None
+    cands = [tuple(DEFAULT_DEVICES)]
+    _inexact_device_division(monkeypatch, 1e-13)
+    try:
+        _assert_matches_oracle(cands, st, raw=raw, policy=policy)
+        # without the host's fix lists that division bills other counts
+        monkeypatch.setattr(executor, "_near_integer_ranks",
+                            lambda lt, r: np.zeros(0, np.int64))
+        (ref,) = evaluate(cands, st, raw=raw, clock_hz=CLOCK, policy=policy)
+        (got,) = evaluate(cands, st, raw=raw, clock_hz=CLOCK, policy=policy,
+                          engine="jax")
+        assert abs(got.energy_j - ref.energy_j) > 1e-6 * ref.energy_j
+    finally:
+        jax.clear_caches()
+
+
+def test_near_integer_ranks():
+    lt = np.sort(np.array([0.0, 0.5e-6, 1e-6, 1.5e-6, 2e-6,
+                           np.nextafter(3e-6, 1.0), 3.01e-6, 7e-6]))
+    assert executor._near_integer_ranks(lt, 1e-6).tolist() == [2, 4, 5, 7]
+    assert executor._near_integer_ranks(lt, np.inf).size == 0
+    assert executor._near_integer_ranks(lt, -np.inf).size == 0
+    # a retention far below the lifetimes, each lifetime tested directly:
+    # every one but the zero is a whole number of nanoseconds
+    assert executor._near_integer_ranks(lt, 1e-9).tolist() == \
+        [1, 2, 3, 4, 5, 6, 7]
+    assert executor._near_integer_ranks(lt, 0.7e-6).tolist() == [7]
+
+
+def _no_fixes(n, rows=8, d=2):
+    """Empty refresh-count fix lists: every entry points past the
+    lifetimes."""
+    return (np.full((rows, d, executor._FIX_MIN), n, np.int64),
+            np.zeros((rows, d, executor._FIX_MIN)))
+
+
 def test_ra_grouped_skips_rows_past_the_real_count():
     args = _ra_slab_inputs(np.random.RandomState(5))
     n_seg = 256
     with enable_x64():
         def call(n_real):
             e, cnt = executor._ra_grouped(
-                *args, np.int64(n_seg - 6), np.int64(n_real), n_seg=n_seg)
+                *args, np.int64(n_seg - 6), np.int64(n_real),
+                *_no_fixes(len(args[4])), n_seg=n_seg)
             return np.asarray(e), np.asarray(cnt)
         e1, c1 = call(1)
         e8, c8 = call(8)

@@ -211,11 +211,10 @@ def test_profile_cli_opstream_bit_for_bit_legacy():
 
     cfg = get_config("tinyllama_1_1b", smoke=False)
 
-    def seed_program(sb):    # the seed's _op_program, verbatim
+    def seed_program(sb):    # the seed's _op_program (a dense config)
         transformer_ops(sb, cfg.d_model, max(cfg.n_heads, 1),
                         max(cfg.kv_heads, 1), cfg.d_ff or 4 * cfg.d_model,
-                        16, n_layers=2, moe_experts=cfg.moe_experts,
-                        moe_topk=cfg.moe_topk)
+                        16, n_layers=2)
 
     session = ProfileSession("opstream")
     session.profile(seed_program, sample=8)
