@@ -26,6 +26,15 @@ shape.  This module removes all three costs for ``engine="jax"``:
   rounded once to float64, so energy differences stay ~1e-16 relative —
   far inside the 1e-9 engine contract.
 
+* **Scatter-free per-address sums** — the grouped refresh-aware kernel
+  picks each address's device from per-address sums of integers (bits,
+  reads·bits, refresh count·bits).  They are int64 prefix sums along
+  the address-sorted lifetimes, built from uint32 scans and read at
+  each address's last lifetime: exact in any order, so the picks are
+  those of a float64 ``segment_sum`` wherever that was exact.  A trace
+  with non-integer values, or sums that could pass 2**62, is refused
+  with ``ValueError`` (:meth:`_TraceResidence.check_int64`).
+
 * **Shape buckets** — inputs are padded to a small pow2 bucket grammar
   (``L`` to ≥2048, ``A`` to ≥256, ``D`` to ≥2, candidates to ≥8; the
   refresh-aware batch is dispatched in fixed-size pow2 candidate slabs
@@ -103,6 +112,13 @@ _SLAB_BYTES = 256 * 1024 * 1024
 # lists of at least _FIX_MIN entries per candidate device.
 _NEAR_INT = 1e-11
 _FIX_MIN = 64
+
+# Exact int64 prefix sums from uint32 scans (:func:`_prefix_sums`): a
+# TPU emulates int64, and its compiler refuses some int64 scans at a few
+# million lifetimes (out of scoped vector memory), while uint32 scans
+# are native.  A block's running sums of 25-bit limbs stay below 2**32.
+_SCAN_BLOCK = 128
+_LIMB_BITS = 25
 
 
 def _next_pow2(n: int, lo: int) -> int:
@@ -182,6 +198,7 @@ class _TraceResidence:
         self._value = None      # (lt_sorted, prefix_bits, prefix_rb, maxlt)
         self._addr = None       # (lt, reads, bits, seg) addr-sorted
         self._orig = None       # (lt, reads, bits) original order
+        self._int_max = None    # (max bits, max reads), once checked
 
     def value_sorted(self, view):
         """+inf-padded value-sorted lifetimes, their [L+1] prefix sums
@@ -215,6 +232,31 @@ class _TraceResidence:
                     self._zpad(view.lt_addr), self._zpad(view.reads_addr),
                     self._zpad(view.bits_addr), seg)))
         return self._addr
+
+    def check_int64(self, view, ret, pad):
+        """Raise ``ValueError`` unless ``_ra_grouped``'s per-address sums
+        are exact in int64 for the candidates ``ret``/``pad`` (``[C, D]``):
+        bits and reads are nonnegative integers (checked once per
+        residence), no live retention is zero or NaN, and no sum of bits
+        times a read or a refresh count reaches 2**62 (O(C·D))."""
+        if self._int_max is None:
+            b, r = view.bits_addr, view.reads_addr
+            if not all(np.isfinite(a).all() and (a >= 0).all()
+                       and (a == np.floor(a)).all() for a in (b, r)):
+                raise ValueError("refresh-aware per-address sums need "
+                                 "nonnegative integer bits and reads")
+            self._int_max = (float(b.max(initial=0)),
+                             float(r.max(initial=0)))
+        live = ret[~pad]
+        if np.isnan(live).any() or (live == 0).any():
+            raise ValueError("refresh-aware retention is zero or NaN")
+        max_bits, max_reads = self._int_max
+        max_lt = float(view.lt_sorted[-1]) if self.n_lt else 0.0
+        r_min = live[live > 0].min(initial=np.inf)
+        limit = 2.0 ** 62 / max(1.0, self.n_lt * max_bits)
+        if not (max_lt / r_min + 1.0 < limit and max_reads < limit):
+            raise ValueError("refresh-aware per-address sums could pass "
+                             "2**62 (int64)")
 
     def original(self, lt, reads, bits):
         """Zero-padded original-order arrays (ungrouped refresh-aware:
@@ -349,24 +391,71 @@ def _rf_fused(ret, read_fj, write_fj, pad, fallback,
     return jax.vmap(one)(ret, read_fj, write_fj, pad, fallback)
 
 
+def _segment_ends(seg, n_lt, n_seg: int):
+    """``[n_seg]`` index of each address's last lifetime in the
+    address-sorted order, by a binary search of the segment ids with the
+    padded lifetimes lifted past every address (so the padded addresses
+    all end at the last real lifetime).  An address with no lifetime
+    ends where the one before it does: -1 before the first lifetime."""
+    key = jnp.where(jnp.arange(seg.shape[0]) < n_lt, seg, n_seg)
+    return jnp.searchsorted(key, jnp.arange(n_seg, dtype=seg.dtype),
+                            side="right") - 1
+
+
+def _prefix_sums(x):
+    """Inclusive prefix sums along the last axis of the nonnegative int64
+    ``x`` (a power-of-two length), exact while the total stays below
+    2**63.  Built from uint32 scans alone: each block of
+    ``_SCAN_BLOCK`` values is cut into limbs of ``_LIMB_BITS`` bits,
+    whose running sums inside the block stay below 2**32, and the
+    blocks' totals are summed the same way one level up."""
+    n = x.shape[-1]
+    b = min(n, _SCAN_BLOCK)
+    blocks = x.reshape(x.shape[:-1] + (n // b, b))
+    inner = jnp.zeros_like(blocks)
+    for k in range(0, 63, _LIMB_BITS):
+        limb = ((blocks >> k) & ((1 << _LIMB_BITS) - 1)).astype(jnp.uint32)
+        inner += jax.lax.cumsum(limb, axis=blocks.ndim - 1).astype(
+            jnp.int64) << k
+    if n == b:
+        return inner.reshape(x.shape)
+    tot = inner[..., -1]
+    return (inner + (_prefix_sums(tot) - tot)[..., None]).reshape(x.shape)
+
+
+def _segment_totals(x, ends):
+    """Each address's sum of the nonnegative int64 ``x`` (``[..., L]``,
+    address-sorted): the prefix sums read at the addresses' last
+    lifetimes (zero at an end of -1), less the previous address's.
+    Exact in any order, with one gather of ``[..., A]`` and no
+    scatter."""
+    p = jnp.where(ends >= 0, _prefix_sums(x)[..., jnp.maximum(ends, 0)], 0)
+    return p - jnp.concatenate([jnp.zeros_like(p[..., :1]), p[..., :-1]],
+                               axis=-1)
+
+
 @functools.partial(jax.jit, static_argnames=("n_seg",))
-def _ra_grouped(ret, read_fj, write_fj, pad,
-                lt, reads, bits, seg, n_addr, n_real, fix_idx, fix_cnt, *,
-                n_seg):
+def _ra_grouped(ret, read_fj, write_fj, pad, lt, reads, bits, seg, n_lt,
+                n_addr, n_real, fix_idx, fix_cnt, *, n_seg):
     """Refresh-aware, one fixed-width candidate slab against the
     resident addr-sorted arrays.  Same decomposition as the PR-9 kernel
-    (separable base terms + one refresh segment sum) so argmin ties
-    resolve identically; the candidate-independent ``segment_sum`` base
-    terms are hoisted out of the candidate loop.  Padded addresses are
-    masked out of the pick counts; padded lifetimes contribute exact
-    zeros.
+    (separable base terms + one refresh sum per address) so argmin ties
+    resolve identically; the candidate-independent base sums are
+    hoisted out of the candidate loop.  Padded addresses are masked out
+    of the pick counts; padded lifetimes contribute exact zeros.
+
+    The per-address sums — bits, reads·bits and each device's refresh
+    count·bits — are sums of integers: int64 prefix sums along the
+    address-sorted lifetimes, differenced at each address's last
+    lifetime (:func:`_segment_totals`), exact in any order and with no
+    scatter.  The caller has checked that they fit
+    (:meth:`_TraceResidence.check_int64`).
 
     Every per-candidate array keeps the lifetime or address axis last
-    ([D, L], [D, A]) and the refresh segment sums run one device row at
-    a time over 1-D data: on a TPU an array whose last axis is the
-    device (or candidate) axis is padded to 128 lanes, which at a few
-    million lifetimes does not fit in HBM.  Candidates run one after
-    another (``lax.map``) for the same reason.
+    ([D, L], [D, A]): on a TPU an array whose last axis is the device
+    (or candidate) axis is padded to 128 lanes, which at a few million
+    lifetimes does not fit in HBM.  Candidates run one after another
+    (``lax.map``) for the same reason.
 
     Only the first ``n_real`` rows (a traced scalar, so the slab's
     shape and its executable stay the same) are candidates: each row
@@ -375,26 +464,29 @@ def _ra_grouped(ret, read_fj, write_fj, pad,
     near an integer quotient come from ``fix_idx``/``fix_cnt``
     (:func:`_refresh_counts`)."""
     rb = reads * bits
-    ss = functools.partial(jax.ops.segment_sum, segment_ids=seg,
-                           num_segments=n_seg, indices_are_sorted=True)
-    ssb = ss(bits)
-    ssrb = ss(rb)
+    ends = _segment_ends(seg, n_lt, n_seg)
+    bits_i = bits.astype(jnp.int64)
+    sb, srb = _segment_totals(
+        jnp.stack([bits_i, reads.astype(jnp.int64) * bits_i]),
+        ends).astype(jnp.float64)                           # [A] each
     amask = jnp.arange(n_seg) < n_addr
     dev_ids = jnp.arange(ret.shape[1])
 
     def one(args):
         ret_r, rf_r, wf_r, pad_r, fi_r, fc_r = args
-        refresh_e = (_refresh_counts(lt, ret_r, fi_r, fc_r)
-                     * bits[None, :])                       # [D, L]
+        n = _refresh_counts(lt, ret_r, fi_r, fc_r)          # [D, L]
+        refresh_e = n * bits[None, :]
         rw = rf_r + wf_r
         e = (wf_r[:, None] * bits[None, :]
              + rf_r[:, None] * rb[None, :]
              + rw[:, None] * refresh_e)
         e = jnp.where(pad_r[:, None], jnp.inf, e)
         energy = e.min(axis=0).sum() * 1e-15
-        per_addr = jnp.stack([
-            wf_r[d] * ssb + rf_r[d] * ssrb + rw[d] * ss(refresh_e[d])
-            for d in range(ret_r.shape[0])])                # [D, A]
+        per_addr = (wf_r[:, None] * sb[None, :]
+                    + rf_r[:, None] * srb[None, :]
+                    + rw[:, None] * _segment_totals(
+                        n.astype(jnp.int64) * bits_i[None, :],
+                        ends).astype(jnp.float64))          # [D, A]
         per_addr = jnp.where(pad_r[:, None], jnp.inf, per_addr)
         ad = jnp.argmin(per_addr, axis=0)
         counts = ((ad[None, :] == dev_ids[:, None])
@@ -527,6 +619,7 @@ def run_batch(pol, batch, view):
         energy = np.empty(C)
         frac = np.empty((C, d_max))
         if grouped:
+            res.check_int64(view, batch.ret_s, batch.pad)
             lt_a, reads_a, bits_a, seg = res.addr_sorted(view)
         else:
             lt_o, reads_o, bits_o = res.original(
@@ -546,7 +639,7 @@ def run_batch(pol, batch, view):
                     # padded rows the kernel skips
                     obs.count("slab_rows_skipped", slab - (hi - lo))
                     e, out = _ra_grouped(ret, rfj, wfj, padm, lt_a,
-                                         reads_a, bits_a, seg, n_addr,
+                                         reads_a, bits_a, seg, n_lt, n_addr,
                                          _put(np.int64(hi - lo)), fix_idx,
                                          fix_cnt, n_seg=res.A_pad)
                 else:

@@ -91,11 +91,15 @@ def test_refresh_aware_executor_compiles_at_floor_buckets(one_chip):
             S((C, D), f64), S((C, D), f64), S((C, D), f64),
             S((C, D), bool), S((L,), f64), S((L,), f64), S((L,), f64),
             S((L,), jnp.int32), S((), jnp.int64), S((), jnp.int64),
-            S((C, D, executor._FIX_MIN), jnp.int64),
+            S((), jnp.int64), S((C, D, executor._FIX_MIN), jnp.int64),
             S((C, D, executor._FIX_MIN), f64), n_seg=A).compile()
     assert compiled.memory_analysis() is not None
+    text = compiled.as_text()
     # padded rows skip the row's work: a branch, not a select of both
-    assert "conditional(" in compiled.as_text()
+    assert "conditional(" in text
+    # the per-address sums are scans: the one scatter left is the fix
+    # lists' (the segment_sum kernel held 2 + D more)
+    assert text.count(" scatter(") == 1
 
 
 def test_lifetime_scan_kernel_compiles_for_tpu(one_chip):

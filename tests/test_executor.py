@@ -22,6 +22,7 @@ The locked contracts:
 """
 
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -214,8 +215,8 @@ def test_ra_grouped_skips_rows_past_the_real_count():
     with enable_x64():
         def call(n_real):
             e, cnt = executor._ra_grouped(
-                *args, np.int64(n_seg - 6), np.int64(n_real),
-                *_no_fixes(len(args[4])), n_seg=n_seg)
+                *args, np.int64(len(args[4])), np.int64(n_seg - 6),
+                np.int64(n_real), *_no_fixes(len(args[4])), n_seg=n_seg)
             return np.asarray(e), np.asarray(cnt)
         e1, c1 = call(1)
         e8, c8 = call(8)
@@ -224,6 +225,177 @@ def test_ra_grouped_skips_rows_past_the_real_count():
     assert (e1[1:] == 0).all() and (c1[1:] == 0).all()
     assert e1[0] == e8[0]
     assert np.array_equal(c1[0], c8[0])
+
+
+# ---------------------------------------------------------------------------
+# per-address sums: int64 prefix scans against the segment_sum rows
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnames=("n_seg",))
+def _segment_sum_rows(ret, read_fj, write_fj, pad, lt, reads, bits, seg,
+                      n_addr, n_real, fix_idx, fix_cnt, *, n_seg):
+    """The refresh-aware slab kernel as it was before the int64 scans,
+    frozen: float64 ``segment_sum`` per-address sums, one device row at
+    a time, padded addresses masked out of the counts."""
+    import jax.numpy as jnp
+    rb = reads * bits
+    ss = functools.partial(jax.ops.segment_sum, segment_ids=seg,
+                           num_segments=n_seg, indices_are_sorted=True)
+    ssb = ss(bits)
+    ssrb = ss(rb)
+    amask = jnp.arange(n_seg) < n_addr
+    dev_ids = jnp.arange(ret.shape[1])
+
+    def one(args):
+        ret_r, rf_r, wf_r, pad_r, fi_r, fc_r = args
+        refresh_e = (executor._refresh_counts(lt, ret_r, fi_r, fc_r)
+                     * bits[None, :])
+        rw = rf_r + wf_r
+        e = (wf_r[:, None] * bits[None, :]
+             + rf_r[:, None] * rb[None, :]
+             + rw[:, None] * refresh_e)
+        e = jnp.where(pad_r[:, None], jnp.inf, e)
+        energy = e.min(axis=0).sum() * 1e-15
+        per_addr = jnp.stack([
+            wf_r[d] * ssb + rf_r[d] * ssrb + rw[d] * ss(refresh_e[d])
+            for d in range(ret_r.shape[0])])
+        per_addr = jnp.where(pad_r[:, None], jnp.inf, per_addr)
+        ad = jnp.argmin(per_addr, axis=0)
+        counts = ((ad[None, :] == dev_ids[:, None])
+                  & amask[None, :]).sum(axis=1)
+        return energy, counts.astype(jnp.float64)
+
+    def skip(_):
+        return jnp.zeros((), jnp.float64), jnp.zeros(ret.shape[1],
+                                                     jnp.float64)
+
+    def row(args):
+        i, cand = args
+        return jax.lax.cond(i < n_real, one, skip, cand)
+
+    rows = jnp.arange(ret.shape[0])
+    return jax.lax.map(row, (rows, (ret, read_fj, write_fj, pad, fix_idx,
+                                    fix_cnt)))
+
+
+def _grouped_slab(seed, n, n_addr, l_pad, a_pad, d, pad_slots, gaps,
+                  rows=8):
+    """One refresh-aware slab's kernel inputs: ``n`` real lifetimes over
+    ``n_addr`` address segments, zero-padded to ``l_pad`` — dense (the
+    first and the last address one lifetime each) or, with ``gaps``,
+    drawn at random with the first address empty; an SRAM slot (+inf
+    retention) first, ``pad_slots`` padded device slots last; and
+    non-empty fix lists with counts the division would not give."""
+    rng = np.random.RandomState(seed)
+    if gaps:
+        seg = np.sort(rng.randint(1, n_addr, n)).astype(np.int32)
+    else:
+        seg = np.sort(np.concatenate([np.arange(n_addr), rng.randint(
+            1, n_addr - 1, n - n_addr)])).astype(np.int32)
+    lt = rng.lognormal(0.0, 2.0, n)
+    lt[::5] = rng.randint(1, 50, lt[::5].size) * 0.25   # whole quotients
+    reads = rng.poisson(3.0, n).astype(np.float64)
+    bits = rng.choice([64.0, 256.0, 1024.0], n)
+    ret = rng.choice([0.25, 0.5, 1.0, 3.0], (rows, d)) \
+        * rng.uniform(0.5, 2.0, (rows, d))
+    ret[:, 0] = np.inf
+    read_fj = rng.uniform(1.0, 5.0, (rows, d))
+    write_fj = rng.uniform(1.0, 5.0, (rows, d))
+    pad = np.zeros((rows, d), bool)
+    if pad_slots:
+        pad[:, -pad_slots:] = True
+        ret[pad], read_fj[pad], write_fj[pad] = -np.inf, np.inf, np.inf
+    fix_idx = np.full((rows, d, executor._FIX_MIN), l_pad, np.int64)
+    fix_cnt = np.zeros((rows, d, executor._FIX_MIN))
+    k = 9
+    fix_idx[:, :, :k] = rng.randint(0, n, (rows, d, k))
+    fix_cnt[:, :, :k] = rng.randint(0, 40, (rows, d, k))
+    z = np.zeros(l_pad)
+    lt_p, reads_p, bits_p = (np.concatenate([a, z[n:]])
+                             for a in (lt, reads, bits))
+    seg_p = np.concatenate([seg, np.zeros(l_pad - n, np.int32)])
+    return (ret, read_fj, write_fj, pad, lt_p, reads_p, bits_p, seg_p,
+            np.int64(n), np.int64(n_addr), fix_idx, fix_cnt, a_pad)
+
+
+# (n, n_addr, l_pad, a_pad, d, pad_slots, gaps, n_real)
+_SLABS = {
+    "padded": (1500, 200, 2048, 256, 4, 1, False, 8),
+    "unpadded": (2048, 256, 2048, 256, 2, 0, False, 8),
+    "few_real_rows": (3000, 700, 4096, 1024, 4, 2, False, 3),
+    "empty_segments": (1500, 400, 2048, 512, 4, 1, True, 8),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("shape", sorted(_SLABS))
+def test_ra_grouped_scans_match_the_segment_sum_rows(shape, seed):
+    n, n_addr, l_pad, a_pad, d, pad_slots, gaps, n_real = _SLABS[shape]
+    (ret, rf, wf, pad, lt, reads, bits, seg, n_lt, n_a, fi, fc,
+     a_pad) = _grouped_slab(seed, n, n_addr, l_pad, a_pad, d, pad_slots,
+                            gaps)
+    assert not gaps or (seg[:n].min() > 0
+                        and len(np.unique(seg[:n])) < n_addr - 1)
+    with enable_x64():
+        e, cnt = executor._ra_grouped(
+            ret, rf, wf, pad, lt, reads, bits, seg, n_lt, n_a,
+            np.int64(n_real), fi, fc, n_seg=a_pad)
+        e0, cnt0 = _segment_sum_rows(
+            ret, rf, wf, pad, lt, reads, bits, seg, n_a, np.int64(n_real),
+            fi, fc, n_seg=a_pad)
+        e, cnt, e0, cnt0 = map(np.asarray, (e, cnt, e0, cnt0))
+    assert np.array_equal(e, e0)
+    assert np.array_equal(cnt, cnt0)
+    # every real row picks a device for every address, none a padded one
+    assert (cnt[:n_real].sum(axis=1) == n_addr).all()
+    assert (cnt[:n_real, d - pad_slots:] == 0).all()
+    assert (cnt[n_real:] == 0).all()
+
+
+@pytest.mark.parametrize("n", [16, 2048, 1 << 15])
+def test_prefix_sums_exact_to_the_int64_limit(n):
+    # values of every limb's width, the total just under 2**63; 2**15
+    # values take two levels of blocks
+    rng = np.random.RandomState(n)
+    x = rng.randint(1 << 61, 1 << 62, (2, n), dtype=np.int64) // n * 2
+    x[:, ::7] = 0
+    x[:, 1::5] = (1 << 25) - 1
+    with enable_x64():
+        got = np.asarray(jax.jit(executor._prefix_sums)(x))
+    assert np.array_equal(got, np.cumsum(x, axis=1))
+    assert got[:, -1].min() > 1 << 61
+
+
+def test_check_int64():
+    st, raw = _synth(n=2000, n_addr=150, seed=4)
+    view = compose_engine.sorted_trace_view(st, raw, CLOCK)
+    res = executor._TraceResidence(view)
+    ret = np.array([[np.inf, 1e-6, -np.inf]])
+    pad = np.array([[False, False, True]])
+    res.check_int64(view, ret, pad)
+    # a padded slot's retention is never read
+    res.check_int64(view, np.array([[np.inf, 1e-6, 0.0]]), pad)
+    # refresh counts past 2**62 / (lifetimes x bits); a zero retention
+    for bad in (1e-20, 0.0, np.nan):
+        with pytest.raises(ValueError):
+            res.check_int64(view, np.array([[np.inf, bad, 1.0]]), pad)
+    frac = dataclasses.replace(st, lifetime_bits=st.lifetime_bits + 0.5)
+    view = compose_engine.sorted_trace_view(frac, raw, CLOCK)
+    with pytest.raises(ValueError, match="integer"):
+        executor._TraceResidence(view).check_int64(view, ret, pad)
+
+
+def test_non_integer_bits_are_refused():
+    st, raw = _synth(n=2500, n_addr=200, seed=9)
+    st = dataclasses.replace(st, lifetime_bits=np.random.RandomState(
+        9).uniform(100.0, 300.0, len(st.lifetime_bits)))
+    cands = _ra_grid_cands()[:9]
+    # the oracle takes them; the grouped kernel's int64 sums cannot
+    assert evaluate(cands, st, raw=raw, clock_hz=CLOCK,
+                    policy="refresh-aware")
+    with pytest.raises(ValueError, match="integer"):
+        evaluate(cands, st, raw=raw, clock_hz=CLOCK,
+                 policy="refresh-aware", engine="jax")
 
 
 def test_slab_rows_skipped_counts_the_padded_rows(monkeypatch):
